@@ -14,12 +14,12 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import get_smoke_config
 from repro.core import topology, wan
 from repro.core.dc_selection import JobModel, algorithm1, best_plan
 from repro.core.simulator import GeoTopology, simulate, testbed_spec
 from repro.data.pipeline import DataConfig, make_batches
+from repro.launch.mesh import make_host_mesh
 from repro.models.transformer import build_model
 from repro.optim.optimizer import OptimizerConfig, init_opt_state, make_train_step
 from repro.parallel.pipeline import make_pipeline_loss
@@ -62,11 +62,11 @@ def main(steps: int = 30):
               f"util={r.utilization:.0%}")
 
     # ---- 3) real cross-pod pipeline on emulated devices ----
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_host_mesh(multi_pod=True)  # (pod=2, data=2, model=2)
     cfg = get_smoke_config("gpt_a")
     model = build_model(cfg)
     print(f"[pipeline] mesh={dict(mesh.shape)} arch={cfg.name} boundary=striped")
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         loss_fn = make_pipeline_loss(cfg, mesh, n_micro=4, boundary="striped")
         step_fn = jax.jit(

@@ -55,5 +55,14 @@ def get_smoke_config(arch: str) -> ModelConfig:
     return mod.SMOKE_CONFIG
 
 
+def load_config(arch: str, *, smoke: bool = False, layers: int = 0) -> ModelConfig:
+    """Full (or smoke) config; ``layers`` > 0 replaces only ``num_layers``
+    (a depth cut at the published widths)."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    return cfg
+
+
 def all_configs() -> Dict[str, ModelConfig]:
     return {a: get_config(a) for a in ARCHS}

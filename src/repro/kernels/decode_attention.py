@@ -24,12 +24,12 @@ def _decode_kernel(
     q_ref,  # (1, 1, D)
     k_ref,  # (1, bkv, D)
     v_ref,
-    kvpos_ref,  # (1, bkv)
-    qpos_ref,  # (1, 1)
+    kvpos_ref,  # (1, 1, bkv)
+    qpos_ref,  # (1, 1, 1)
     o_ref,  # (1, 1, D)
-    acc_ref,
-    m_ref,
-    l_ref,
+    acc_ref,  # (1, D)
+    m_ref,  # (1, 1)
+    l_ref,  # (1, 1)
     *,
     scale: float,
     window: int,
@@ -43,32 +43,32 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # (D,)
+    # every operand stays 2-D (rows x lanes), as the TPU vector layout wants
+    q = q_ref[0].astype(jnp.float32) * scale  # (1, D)
     k = k_ref[0].astype(jnp.float32)  # (bkv, D)
     v = v_ref[0].astype(jnp.float32)
-    kv_pos = kvpos_ref[0]  # (bkv,)
-    q_pos = qpos_ref[0, 0]
+    kv_pos = kvpos_ref[0]  # (1, bkv)
+    q_pos = qpos_ref[0]  # (1, 1)
 
-    s = jnp.dot(k, q)  # (bkv,)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (1, bkv)
     valid = (kv_pos >= 0) & (kv_pos <= q_pos)
     if window > 0:
         valid = valid & (kv_pos > q_pos - window)
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[0]
-    l_prev = l_ref[0]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
-    l_ref[0] = l_prev * alpha + jnp.sum(p)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(p, v)[None]
-    m_ref[0] = m_new
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(p, v)
+    m_ref[...] = m_new
 
     @pl.when(ki == num_kv_blocks - 1)
     def _finish():
-        l = l_ref[0]
+        l = l_ref[...]
         denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[0] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def decode_attention_bhsd(
@@ -100,15 +100,15 @@ def decode_attention_bhsd(
             pl.BlockSpec((1, 1, D), lambda i, k_: (i, 0, 0)),
             pl.BlockSpec((1, block_kv, D), lambda i, k_, g=group: (i // g, k_, 0)),
             pl.BlockSpec((1, block_kv, D), lambda i, k_, g=group: (i // g, k_, 0)),
-            pl.BlockSpec((1, block_kv), lambda i, k_, g=group: (i // g, k_)),
-            pl.BlockSpec((1, 1), lambda i, k_: (i, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda i, k_, g=group: (i // g, 0, k_)),
+            pl.BlockSpec((1, 1, 1), lambda i, k_: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, D), lambda i, k_: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, 1, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, kv_pos, q_pos)
+    )(q, k, v, kv_pos[:, None, :], q_pos[:, :, None])

@@ -1,9 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
 These accept the model-layer layouts ((B, T, H, D) etc.), reshape to the
-kernel layouts, pick interpret mode automatically (interpret=True anywhere
-but real TPU), and fall back to the jnp reference for shapes the kernels
-do not support (e.g. non-divisible blocks).
+kernel layouts and run the kernels compiled, except on the CPU backend,
+where they run in interpret mode.  A shape that does not divide into the
+kernel's blocks raises ``ValueError``; callers that must handle such
+shapes check ``flash_attention_fits``/``decode_attention_fits`` first and
+stay on XLA.
 """
 from __future__ import annotations
 
@@ -15,13 +17,34 @@ import jax.numpy as jnp
 
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
-from repro.kernels import ref as _ref
 from repro.kernels import rmsnorm as _rms
 from repro.kernels import wkv6 as _wkv
 
 
+FLASH_BLOCK = 128
+DECODE_BLOCK_KV = 256
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
+
+
+def _check_blocks(kernel: str, shape, dims, blocks) -> None:
+    if any(n % b for n, b in zip(dims, blocks)):
+        raise ValueError(
+            f"{kernel}: shape {tuple(shape)} does not divide into blocks "
+            f"{tuple(blocks)} along dims {tuple(dims)}"
+        )
+
+
+def flash_attention_fits(T: int, S: int) -> bool:
+    """True when the default flash blocks tile a (T, S) attention."""
+    return T % min(FLASH_BLOCK, T) == 0 and S % min(FLASH_BLOCK, S) == 0
+
+
+def decode_attention_fits(S: int) -> bool:
+    """True when the default decode block tiles an S-slot cache."""
+    return S % min(DECODE_BLOCK_KV, S) == 0
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q", "block_kv"))
@@ -32,15 +55,14 @@ def flash_attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_kv: int = 128,
+    block_q: int = FLASH_BLOCK,
+    block_kv: int = FLASH_BLOCK,
 ) -> jax.Array:
     B, T, Hq, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     scale = scale if scale is not None else D**-0.5
     bq, bkv = min(block_q, T), min(block_kv, S)
-    if T % bq or S % bkv:
-        return _ref.flash_attention_ref(q, k, v, causal=causal, scale=scale)
+    _check_blocks("flash_attention", q.shape, (T, S), (bq, bkv))
     group = Hq // Hkv
     qr = q.transpose(0, 2, 1, 3).reshape(B * Hq, T, D)
     kr = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, D)
@@ -62,15 +84,14 @@ def decode_attention(
     *,
     window: Optional[int] = None,
     scale: Optional[float] = None,
-    block_kv: int = 256,
+    block_kv: int = DECODE_BLOCK_KV,
 ) -> jax.Array:
     B, T, Hq, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     assert T == 1
     scale = scale if scale is not None else D**-0.5
     bkv = min(block_kv, S)
-    if S % bkv:
-        return _ref.decode_attention_ref(q, k, v, q_pos, kv_pos, window=window, scale=scale)
+    _check_blocks("decode_attention", k.shape, (S,), (bkv,))
     group = Hq // Hkv
     qr = q.transpose(0, 2, 1, 3).reshape(B * Hq, 1, D)
     kr = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, D)
@@ -92,8 +113,7 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6, block_rows: in
         N *= s
     xr = x.reshape(N, shape[-1])
     br = min(block_rows, N)
-    if N % br:
-        return _ref.rmsnorm_ref(x, scale, eps)
+    _check_blocks("rmsnorm", shape, (N,), (br,))
     o = _rms.rmsnorm_rows(xr, scale, eps=eps, block_rows=br, interpret=_interpret())
     return o.reshape(shape)
 
@@ -110,8 +130,7 @@ def wkv6(
 ) -> jax.Array:
     B, T, H, D = r.shape
     c = min(chunk, T)
-    if T % c:
-        return _ref.wkv6_ref(r, k, v, logw, u)
+    _check_blocks("wkv6", r.shape, (T,), (c,))
     tr = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     ub = jnp.tile(u, (B, 1))  # (B*H, D)
     o = _wkv.wkv6_bhtd(

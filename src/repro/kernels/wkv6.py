@@ -19,13 +19,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# f32 matmuls at full precision: the recurrence compounds rounding error
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _wkv6_kernel(
     r_ref,  # (1, C, D)
     k_ref,
     v_ref,
     lw_ref,  # (1, C, D) log decay (<= 0), f32
-    u_ref,  # (1, D)
+    u_ref,  # (1, 1, D)
     o_ref,  # (1, C, D)
     state_ref,  # scratch (D, D) f32  [key-dim x value-dim]
     *,
@@ -41,30 +44,34 @@ def _wkv6_kernel(
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     lw = lw_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)  # (D,)
+    u = u_ref[0].astype(jnp.float32)  # (1, D)
 
-    lcum_inc = jnp.cumsum(lw, axis=0)  # inclusive
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over time as a lower-triangular matmul (MXU)
+    tri = (t_idx >= s_idx).astype(jnp.float32)
+    lcum_inc = jnp.dot(tri, lw, precision=_HIGHEST)
     lcum = lcum_inc - lw  # exclusive
-    ltot = lcum_inc[-1]  # (D,)
+    ltot = lcum_inc[chunk - 1 :]  # (1, D)
 
     r_sc = r * jnp.exp(lcum)
     k_sc = k * jnp.exp(-lcum_inc)
-    scores = jnp.dot(r_sc, k_sc.T)  # (C, C)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    scores = jnp.dot(r_sc, k_sc.T, precision=_HIGHEST)  # (C, C)
     scores = jnp.where(t_idx > s_idx, scores, 0.0)  # strictly causal
-    y = jnp.dot(scores, v)
+    y = jnp.dot(scores, v, precision=_HIGHEST)
     # current-token bonus
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)  # (C,)
-    y = y + diag[:, None] * v
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)  # (C, 1)
+    y = y + diag * v
     # inter-chunk from carried state
-    y = y + jnp.dot(r_sc, state_ref[...])
+    y = y + jnp.dot(r_sc, state_ref[...], precision=_HIGHEST)
 
     o_ref[0] = y.astype(o_ref.dtype)
 
     # update state: S = diag(prod w) S + sum_s exp(ltot - lcum_inc_s) k_s v_sᵀ
-    kw = k * jnp.exp(ltot[None, :] - lcum_inc)
-    state_ref[...] = state_ref[...] * jnp.exp(ltot)[:, None] + jnp.dot(kw.T, v)
+    kw = k * jnp.exp(ltot - lcum_inc)
+    state_ref[...] = state_ref[...] * jnp.exp(ltot).T + jnp.dot(
+        kw.T, v, precision=_HIGHEST
+    )
 
 
 def wkv6_bhtd(
@@ -90,10 +97,10 @@ def wkv6_bhtd(
             pl.BlockSpec((1, chunk, D), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, chunk, D), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, chunk, D), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1, D), lambda i, c: (i, 0)),
+            pl.BlockSpec((1, 1, D), lambda i, c: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, D), lambda i, c: (i, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), r.dtype),
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u)
+    )(r, k, v, logw, u[:, None, :])

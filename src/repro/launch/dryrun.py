@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
-
 """Multi-pod dry-run — deliverable (e).
 
 For every (architecture × input shape × mesh) combination:
@@ -17,9 +13,14 @@ combo) so interrupted sweeps resume. Usage:
   PYTHONPATH=src python -m repro.launch.dryrun                 # everything
   PYTHONPATH=src python -m repro.launch.dryrun --arch granite-34b \
       --shape train_4k --mesh multi --boundary striped
+
+``main`` asks for 512 host devices through ``XLA_FLAGS`` before the
+backend starts, adding the flag to any ``XLA_FLAGS`` already set; merely
+importing this module changes nothing.
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -29,10 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import ARCHS, canon
 from repro.launch import shapes as shp
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import auto_mesh, make_production_mesh
 from repro.models.transformer import build_model
 from repro.optim.optimizer import OptimizerConfig, init_opt_state, make_train_step
 from repro.parallel.pipeline import make_pipeline_loss
@@ -159,13 +159,13 @@ def build_lowerable(arch: str, shape: str, multi_pod: bool, boundary: str = "str
     model = build_model(cfg)
     if relayout and not multi_pod:
         tp = head_aligned_tp(cfg)
-        mesh = jax.make_mesh((256 // tp, tp), ("data", "model"))
+        mesh = auto_mesh((256 // tp, tp), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     kind = shp.SHAPES[shape]["kind"]
     opt_cfg = OptimizerConfig()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params_sds = _sds_params(model, mesh, fsdp=fsdp)
         if kind == "train":
             if multi_pod:
@@ -387,7 +387,7 @@ def run_one(arch: str, shape: str, mesh_name: str, boundary: str = "striped",
     t0 = time.time()
     mesh, fn, args, cfg = build_lowerable(arch, shape, multi_pod, boundary,
                                           fsdp=fsdp, relayout=relayout)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -455,7 +455,18 @@ def run_one(arch: str, shape: str, mesh_name: str, boundary: str = "striped",
     return result
 
 
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count"
+
+
+def _request_host_devices(n: int = 512) -> None:
+    """Ask the CPU backend for ``n`` devices; must run before it starts."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if HOST_DEVICES_FLAG not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {HOST_DEVICES_FLAG}={n}".strip()
+
+
 def main():
+    _request_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(shp.SHAPES) + [None])

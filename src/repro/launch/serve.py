@@ -1,8 +1,10 @@
 """Serving driver: batched prefill/decode with the Splitwise-style split
 (paper §5) and BubbleTea admission statistics.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch gpt-a --requests 16 \
-      --max-new 8
+  PYTHONPATH=src python -m repro.launch.serve --arch gpt-a --smoke \
+      --requests 16 --max-new 8
+  PYTHONPATH=src python -m repro.launch.serve --arch gpt-a --layers 2 \
+      --requests 4 --max-new 4      # GPT-A widths, one TPU v5e chip
 """
 from __future__ import annotations
 
@@ -12,15 +14,19 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import get_smoke_config
-from repro.core.bubbletea import InferenceModelSpec, PrefillLatencyModel
+from repro.configs import load_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import build_model
 from repro.serving.engine import Request, ServingEngine, SplitwiseCluster
 
 
 def main(argv=None):
+    """Serve seeded random requests; returns them with their tokens."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt-a")
+    ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="replace the config's num_layers (depth cut)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -29,8 +35,9 @@ def main(argv=None):
     ap.add_argument("--splitwise", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = get_smoke_config(args.arch)
+    cfg = load_config(args.arch, smoke=args.smoke, layers=args.layers)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)
@@ -59,16 +66,14 @@ def main(argv=None):
 
     ttfts = [r.ttft_ms for r in done]
     tbts = [t for r in done for t in r.tbt_ms]
-    print(f"[serve] arch={cfg.name} requests={len(done)} wall={wall:.2f}s")
+    print(f"[serve] arch={cfg.name} layers={cfg.num_layers} d_model={cfg.d_model} "
+          f"requests={len(done)} wall={wall:.2f}s")
     print(f"  TTFT ms: p50={np.percentile(ttfts,50):.1f} p99={np.percentile(ttfts,99):.1f}")
     if tbts:
         print(f"  TBT  ms: p50={np.percentile(tbts,50):.1f} p99={np.percentile(tbts,99):.1f}")
     if args.splitwise:
         print(f"  KV bytes moved: {cluster.kv_bytes_moved/1e6:.2f} MB")
-    # reference: analytic TTFT model (paper Fig 14) for A100-class serving
-    lm = PrefillLatencyModel(InferenceModelSpec("llama3-8b", 8e9))
-    print(f"  [model] A100 TTFT(512, PP=1)={lm.ttft_ms(512,1):.0f}ms "
-          f"(8192, PP=8)={lm.ttft_ms(8192,8):.0f}ms")
+    return done
 
 
 if __name__ == "__main__":

@@ -1,14 +1,18 @@
 """End-to-end training driver.
 
-On real hardware this runs the production mesh; on CPU it runs the same
-code path on the host mesh with a reduced (smoke) config — the driver
-logic (data pipeline -> sharded train step -> metrics -> async
-checkpoints) is identical.
+Runs on the host mesh built from the visible devices (one chip, a
+four-chip host, or CPU virtual devices), or on the production mesh.  The
+loop (data pipeline -> sharded train step -> metrics -> async
+checkpoints) is the same for the reduced (``--smoke``) config, the full
+config, and the full config cut to ``--layers N`` (published widths,
+fewer layers: what fits one chip).
 
   PYTHONPATH=src python -m repro.launch.train --arch gpt-a --steps 200 \
-      --batch 8 --seq 128 --smoke --ckpt-dir /tmp/ckpt
+      --batch 8 --seq 128 --smoke --ckpt-dir ckpt
+  PYTHONPATH=src python -m repro.launch.train --arch gpt-a --layers 2 \
+      --steps 4 --batch 1 --seq 1536      # GPT-A widths, one TPU v5e chip
   PYTHONPATH=src python -m repro.launch.train --arch zamba2-2.7b --smoke \
-      --pipeline --steps 20        # cross-pod pipeline path (needs >=8 devs)
+      --pipeline --steps 20        # cross-pod pipeline path (even device count)
 """
 from __future__ import annotations
 
@@ -18,10 +22,10 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-from repro.configs import canon, get_config, get_smoke_config
+from repro.configs import load_config
 from repro.ckpt.checkpoint import AsyncCheckpointer
 from repro.data.pipeline import DataConfig, make_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.transformer import build_model
 from repro.optim.optimizer import OptimizerConfig, init_opt_state, make_train_step
@@ -29,7 +33,17 @@ from repro.parallel.pipeline import make_pipeline_loss
 from repro.parallel.sharding import make_batch_shardings, make_param_shardings
 
 
+def print_memory_analysis(tag: str, compiled) -> None:
+    """Per-device bytes of a compiled program, as its compiler counts them."""
+    m = compiled.memory_analysis()
+    gib = lambda b: f"{b / 2**30:.2f}"
+    print(f"[{tag}] memory_analysis GiB: args={gib(m.argument_size_in_bytes)} "
+          f"out={gib(m.output_size_in_bytes)} temp={gib(m.temp_size_in_bytes)} "
+          f"alias={gib(m.alias_size_in_bytes)}", flush=True)
+
+
 def main(argv=None):
+    """Train; returns the per-step metrics (host floats), one dict a step."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
@@ -37,6 +51,8 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="replace the config's num_layers (depth cut)")
     ap.add_argument("--pipeline", action="store_true", help="PP over pod axis")
     ap.add_argument("--n-micro", type=int, default=4)
     ap.add_argument("--boundary", default="striped", choices=["striped", "direct"])
@@ -46,18 +62,21 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    cfg = load_config(args.arch, smoke=args.smoke, layers=args.layers)
     model = build_model(cfg)
     if args.production_mesh:
         mesh = make_production_mesh(multi_pod=args.pipeline)
     else:
         mesh = make_host_mesh(multi_pod=args.pipeline)
-    print(f"[train] arch={cfg.name} mesh={dict(mesh.shape)} params={cfg.param_count()/1e6:.1f}M")
+    print(f"[train] arch={cfg.name} layers={cfg.num_layers} d_model={cfg.d_model} "
+          f"d_ff={cfg.d_ff} vocab={cfg.vocab_size} batch={args.batch} seq={args.seq} "
+          f"mesh={dict(mesh.shape)} params={cfg.param_count()/1e6:.1f}M", flush=True)
 
     opt_cfg = OptimizerConfig(peak_lr=args.lr, warmup_steps=min(20, args.steps // 5 + 1),
                               total_steps=args.steps)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(args.seed))
         p_sh = make_param_shardings(jax.eval_shape(lambda: params), mesh)
         params = jax.tree.map(lambda x, s: jax.device_put(x, s), params, p_sh)
@@ -73,13 +92,18 @@ def main(argv=None):
         ckpt = AsyncCheckpointer(args.ckpt_dir) if args.ckpt_dir else None
         data = make_batches(cfg, DataConfig(seed=args.seed, batch_size=args.batch,
                                             seq_len=args.seq), num_steps=args.steps)
+        history = []
         t0 = time.time()
         tokens_done = 0
         for step, batch in enumerate(data):
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
             b_sh = make_batch_shardings(jax.eval_shape(lambda: batch), mesh)
             batch = jax.tree.map(lambda x, s: jax.device_put(x, s), batch, b_sh)
+            if step == 0:
+                print_memory_analysis(
+                    "train", step_fn.lower(params, opt_state, batch).compile())
             params, opt_state, metrics = step_fn(params, opt_state, batch)
+            history.append(metrics)
             tokens_done += args.batch * args.seq
             if step % args.log_every == 0 or step == args.steps - 1:
                 loss = float(metrics["loss"])
@@ -96,7 +120,7 @@ def main(argv=None):
                       {"step": args.steps})
             ckpt.close()
             print(f"[train] checkpoint at {ckpt.latest_path()}")
-    return params
+    return [{k: float(v) for k, v in m.items()} for m in jax.device_get(history)]
 
 
 if __name__ == "__main__":

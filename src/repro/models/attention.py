@@ -134,16 +134,17 @@ def sdpa(
     G = Hq // Hkv
     scale = scale if scale is not None else D**-0.5
 
-    if _IMPL == "pallas" and T > 1 and window is None and q_pos.shape == kv_pos.shape:
+    if _IMPL == "pallas":
         from repro.kernels import ops as kops
 
-        return kops.flash_attention(q, k, v, causal=causal, scale=scale)
-    if _IMPL == "pallas" and T == 1:
-        from repro.kernels import ops as kops
-
-        return kops.decode_attention(
-            q, k, v, q_pos, kv_pos, window=window, scale=scale
-        )
+        # shapes the kernels' blocks do not tile stay on XLA
+        if (T > 1 and window is None and q_pos.shape == kv_pos.shape
+                and kops.flash_attention_fits(T, S)):
+            return kops.flash_attention(q, k, v, causal=causal, scale=scale)
+        if T == 1 and kops.decode_attention_fits(S):
+            return kops.decode_attention(
+                q, k, v, q_pos, kv_pos, window=window, scale=scale
+            )
 
     qf = (q.astype(jnp.float32) * scale).reshape(B, T, Hkv, G, D)
     scores = jnp.einsum("btkgd,bskd->bkgts", qf, k.astype(jnp.float32))

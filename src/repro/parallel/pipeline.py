@@ -37,9 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
-
 from repro.models.modules import ModelConfig
 from repro.models.transformer import PipelineParts, build_pipeline_parts
 from repro.parallel.sharding import constrain, constraints_disabled
@@ -144,11 +141,10 @@ def make_pipeline_loss(
             boundary=boundary,
             token_input=token_input,
         )
-        sm = shard_map(
+        sm = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(
-                P("pod"),
                 jax.tree.map(lambda _: P("pod"), layers),
                 jax.tree.map(lambda _: P(), rest),
                 inp_spec,
@@ -157,31 +153,30 @@ def make_pipeline_loss(
                 P(None, "data", None),
             ),
             out_specs=(P(), P()),
-            # partial-auto (GSPMD keeps handling TP on ``model``) where
-            # supported; otherwise fully manual with the model axis
-            # carrying replicas — same numerics, no TP overlap.
-            axis_names={"pod", "data"}
-            if compat.PARTIAL_AUTO_SUPPORTED
-            else set(mesh.axis_names),
+            # partial-auto: GSPMD keeps handling TP on ``model``
+            axis_names={"pod", "data"},
             check_vma=False,
         )
-        # stage id travels as a pod-sharded iota: lax.axis_index lowers to
-        # a PartitionId instruction old XLA cannot SPMD-partition in a
-        # partial-auto region, while a sliced input partitions trivially.
-        stage_ids = jnp.arange(S, dtype=jnp.int32)
-        loss, aux = sm(stage_ids, layers, rest, inp_mb, pos_mb, t_mb, m_mb)
+        loss, aux = sm(layers, rest, inp_mb, pos_mb, t_mb, m_mb)
         return loss + aux
 
     return loss_fn
 
 
+def _constrain_auto(x, spec):
+    """``with_sharding_constraint`` over the axes the manual region leaves
+    automatic (``model``), on the ambient abstract mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return jax.lax.with_sharding_constraint(x, jax.sharding.NamedSharding(mesh, spec))
+
+
 def _pipeline_inner(
-    stage_ids, layers, rest, inp_mb, pos_mb, t_mb, m_mb, *, parts, cfg, S, DP,
+    layers, rest, inp_mb, pos_mb, t_mb, m_mb, *, parts, cfg, S, DP,
     n_micro, boundary, token_input,
 ):
     """Manual over {pod, data}: ``layers`` is this stage's (L/S, ...) slice;
     token arrays are this data-shard's slice."""
-    my = stage_ids[0]  # this pod's stage index (see caller)
+    my = jax.lax.axis_index("pod")  # this pod's stage index
     steps = n_micro + S - 1
     if token_input:
         # embedding lookup with device-local indices: the VJP scatter-add
@@ -222,11 +217,9 @@ def _pipeline_inner(
         # NB: must force the sharding even when it is full replication
         # (repro.parallel.sharding.constrain treats all-None as a no-op),
         # otherwise GSPMD propagation picks its own layout and the two
-        # modes become indistinguishable.  On old jax the constraint is
-        # unsupported inside the manual region (compat.constrain_auto
-        # no-ops) and GSPMD stripes on its own.
+        # modes become indistinguishable.
         if boundary == "striped":
-            y_send = compat.constrain_auto(y, P(None, None, "model"))
+            y_send = _constrain_auto(y, P(None, None, "model"))
             buf_next = jax.lax.ppermute(
                 y_send, "pod", [(i, i + 1) for i in range(S - 1)]
             )
@@ -236,13 +229,13 @@ def _pipeline_inner(
             # without it XLA's partitioner reshards before the permute and
             # re-gathers after, i.e. GSPMD performs the Atlas striping
             # automatically (see EXPERIMENTS.md §Perf B).
-            y_send = compat.constrain_auto(y, P(None, None, None))
+            y_send = _constrain_auto(y, P(None, None, None))
             y_send = jax.lax.optimization_barrier(y_send)
             buf_next = jax.lax.ppermute(
                 y_send, "pod", [(i, i + 1) for i in range(S - 1)]
             )
             buf_next = jax.lax.optimization_barrier(buf_next)
-        buf_next = compat.constrain_auto(buf_next, P(None, None, None))
+        buf_next = _constrain_auto(buf_next, P(None, None, None))
 
         # ---- loss on the last stage ----
         m_out = t - (S - 1)

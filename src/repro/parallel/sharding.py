@@ -39,13 +39,8 @@ def constraints_disabled():
 
 
 def _ambient_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:  # pragma: no cover - very old jax
-        return None
-    if m is None or getattr(m, "empty", True):
-        return None
-    return m
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _fit_spec(shape: Tuple[int, ...], spec: P, mesh) -> Optional[P]:

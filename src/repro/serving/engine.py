@@ -94,6 +94,9 @@ class ServingEngine:
         self.max_batch = max_batch
         self.max_len = max_len
         self._prefill = jax.jit(self.model.prefill)
+        model = self.model  # not self: a closure over self would make a
+        # reference cycle that keeps the weights on the device after the
+        # engine is dropped, until the cycle collector happens to run
 
         def _prefill_masked(params, batch, cache):
             # ragged batches carry pad slots at position -1; the pallas
@@ -101,7 +104,7 @@ class ServingEngine:
             # sdpa at trace time (the decode kernel DOES mask kv_pos<0,
             # so decode needs no pinning).
             with attention.force_impl("xla"):
-                return self.model.prefill(params, batch, cache)
+                return model.prefill(params, batch, cache)
 
         self._prefill_masked = jax.jit(_prefill_masked)
         self._decode = jax.jit(self.model.decode_step)
@@ -110,8 +113,8 @@ class ServingEngine:
         # ragged batches must be served per-request (see generate/serve)
         self._recurrent = cfg.rwkv is not None or cfg.family in ("ssm", "hybrid")
 
-    def prefill_batch(self, requests: List[Request]) -> Tuple[Any, jax.Array, jax.Array]:
-        """Right-aligned batched prefill. Returns (cache, next_tokens, pos).
+    def prefill_logits(self, requests: List[Request]) -> Tuple[jax.Array, Any]:
+        """Right-aligned batched prefill. Returns (last-token logits, cache).
 
         Pad slots carry position -1 — ``sdpa``/the decode kernel treat
         negative positions as empty and mask them, so for attention
@@ -133,12 +136,17 @@ class ServingEngine:
             positions = jnp.broadcast_to(positions[None], (3,) + positions.shape)
         cache = zeros_cache(self.model, B, self.max_len)
         prefill = self._prefill_masked if self._ragged(requests) else self._prefill
-        t0 = time.perf_counter()
-        logits, cache = prefill(
+        return prefill(
             self.params,
             {"tokens": jnp.asarray(toks), "positions": positions},
             cache,
         )
+
+    def prefill_batch(self, requests: List[Request]) -> Tuple[Any, jax.Array, jax.Array]:
+        """``prefill_logits`` plus the first sample and TTFT.
+        Returns (cache, next_tokens, pos)."""
+        t0 = time.perf_counter()
+        logits, cache = self.prefill_logits(requests)
         logits.block_until_ready()
         wall = (time.perf_counter() - t0) * 1e3
         for r in requests:

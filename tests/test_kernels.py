@@ -114,11 +114,43 @@ def test_decode_attention_multiblock_default_blocks():
 
 
 def test_flash_attention_fallback_on_ragged_shapes():
-    """Non-divisible block shapes must fall back to the reference path."""
+    """Non-divisible block shapes raise an error naming the shape and the
+    blocks; the kernel never quietly computes the reference instead."""
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (1, 100, 2, 32))
     k = jax.random.normal(ks[1], (1, 100, 2, 32))
     v = jax.random.normal(ks[2], (1, 100, 2, 32))
-    o = ops.flash_attention(q, k, v, causal=True, block_q=64, block_kv=64)
+    with pytest.raises(ValueError, match=r"\(1, 100, 2, 32\).*blocks \(64, 64\)"):
+        ops.flash_attention(q, k, v, causal=True, block_q=64, block_kv=64)
+
+
+@pytest.mark.parametrize("kernel,call", [
+    ("decode_attention", lambda x, pos: ops.decode_attention(
+        x[:, :1], x, x, pos[:, :1], pos, block_kv=64)),
+    ("rmsnorm", lambda x, pos: ops.rmsnorm(x, jnp.ones((32,)), block_rows=64)),
+    ("wkv6", lambda x, pos: ops.wkv6(x, x, x, -jnp.ones_like(x), jnp.ones((2, 32)),
+                                     chunk=64)),
+])
+def test_kernels_raise_on_ragged_shapes(kernel, call):
+    x = jnp.ones((1, 100, 2, 32))
+    pos = jnp.broadcast_to(jnp.arange(100, dtype=jnp.int32)[None], (1, 100))
+    with pytest.raises(ValueError, match=kernel + r": shape .* blocks"):
+        call(x, pos)
+
+
+def test_sdpa_keeps_ragged_shapes_on_xla():
+    """The attention entry picks XLA for shapes the kernels cannot tile,
+    so the pallas impl still answers (and matches the XLA path)."""
+    from repro.models import attention
+
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    q = jax.random.normal(ks[0], (1, 200, 2, 32))
+    k = jax.random.normal(ks[1], (1, 200, 2, 32))
+    v = jax.random.normal(ks[2], (1, 200, 2, 32))
+    pos = jnp.broadcast_to(jnp.arange(200, dtype=jnp.int32)[None], (1, 200))
+    assert not ops.flash_attention_fits(200, 200)
+    assert not ops.decode_attention_fits(300)
+    with attention.force_impl("pallas"):
+        o = attention.sdpa(q, k, v, pos, pos, causal=True)
     o_ref = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=1e-5, rtol=1e-5)

@@ -1,6 +1,6 @@
-"""Cross-pod pipeline tests — run in a subprocess with 8 fake devices
-(jax locks the device count at first init, so the main pytest process
-cannot host these)."""
+"""Cross-pod pipeline tests — run in a subprocess with 8 (or 4) fake
+devices (jax locks the device count at first init, so the main pytest
+process cannot host these)."""
 import os
 import subprocess
 import sys
@@ -8,14 +8,12 @@ import textwrap
 
 import pytest
 
-pytestmark = pytest.mark.slow  # compile-heavy: excluded from tier-1
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(code: str) -> str:
+def _run(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
@@ -31,11 +29,12 @@ import jax, jax.numpy as jnp
 from repro.configs import get_smoke_config
 from repro.models.transformer import build_model
 from repro.parallel.pipeline import make_pipeline_loss
-from repro import compat
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(multi_pod=True)
 """
 
 
+@pytest.mark.slow  # compile-heavy: excluded from tier-1
 @pytest.mark.parametrize("arch", ["minitron_4b", "zamba2_2p7b", "deepseek_v2_lite_16b"])
 def test_pipeline_matches_reference_loss(arch):
     out = _run(PREAMBLE + f"""
@@ -44,7 +43,7 @@ m = build_model(cfg)
 params = m.init(jax.random.PRNGKey(0))
 batch = {{"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)}}
 ref, _ = jax.jit(m.loss)(params, batch)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     s = jax.jit(make_pipeline_loss(cfg, mesh, n_micro=4, boundary="striped"))(params, batch)
     d = jax.jit(make_pipeline_loss(cfg, mesh, n_micro=4, boundary="direct"))(params, batch)
 assert abs(float(s) - float(ref)) < 3e-2, (float(s), float(ref))
@@ -54,13 +53,14 @@ print("OK", float(s), float(ref))
     assert "OK" in out
 
 
+@pytest.mark.slow  # compile-heavy: excluded from tier-1
 def test_pipeline_gradients_match_reference():
     out = _run(PREAMBLE + """
 cfg = get_smoke_config("minitron_4b")
 m = build_model(cfg)
 params = m.init(jax.random.PRNGKey(0))
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.jit(jax.grad(make_pipeline_loss(cfg, mesh, n_micro=4)))(params, batch)
 g0 = jax.jit(jax.grad(lambda p, b: m.loss(p, b)[0]))(params, batch)
 num = sum(float(jnp.sum(jnp.abs(a.astype(jnp.float32)-b.astype(jnp.float32)))) for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g0)))
@@ -71,6 +71,7 @@ print("OK", num / den)
     assert "OK" in out
 
 
+@pytest.mark.slow  # compile-heavy: excluded from tier-1
 def test_striped_boundary_dcn_bytes():
     """Atlas striping never sends MORE inter-pod bytes than the direct
     boundary — and (EXPERIMENTS.md §Perf B) XLA's partitioner performs
@@ -83,7 +84,7 @@ m = build_model(cfg)
 params_sds = jax.eval_shape(m.init, jax.random.PRNGKey(0))
 batch = {"tokens": jax.ShapeDtypeStruct((8, 32), jnp.int32)}
 res = {}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for mode in ("striped", "direct"):
         lf = make_pipeline_loss(cfg, mesh, n_micro=4, boundary=mode)
         compiled = jax.jit(lf).lower(params_sds, batch).compile()
@@ -95,6 +96,7 @@ assert res["striped"]["dcn"] <= res["direct"]["dcn"], res
     assert "striped" in out
 
 
+@pytest.mark.slow  # compile-heavy: excluded from tier-1
 def test_identity_padding_is_exact():
     """27-layer (deepseek) and 9-group (zamba2) stacks pad to uniform
     stages without changing the function (checked vs reference loss)."""
@@ -108,7 +110,7 @@ for arch in ("deepseek_v2_lite_16b", "zamba2_2p7b"):
     params = m.init(jax.random.PRNGKey(0))
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)}
     ref, _ = jax.jit(m.loss)(params, batch)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         s = jax.jit(make_pipeline_loss(cfg, mesh, n_micro=4))(params, batch)
     assert abs(float(s) - float(ref)) < 3e-2, (arch, float(s), float(ref))
 print("OK")
@@ -116,6 +118,7 @@ print("OK")
     assert "OK" in out
 
 
+@pytest.mark.slow  # compile-heavy: excluded from tier-1
 def test_dryrun_smoke_combo_on_host_mesh():
     """A miniature dry-run (host-mesh sized) proves the lowering path."""
     out = _run("""
@@ -134,4 +137,24 @@ assert c["by_op"]["all-reduce"] == 16*16*4*2
 assert c["dcn"] == 16, c  # the permute crosses the pod stride
 print("OK")
 """)
+    assert "OK" in out
+
+
+def test_pipeline_matches_reference_on_four_devices():
+    """Tier-1 guard for the four-chip layout (pod=2, data=1, model=2):
+    both boundaries give the plain model's loss."""
+    out = _run(PREAMBLE + """
+assert dict(mesh.shape) == {"pod": 2, "data": 1, "model": 2}, mesh.shape
+cfg = get_smoke_config("gpt_a")
+m = build_model(cfg)
+params = m.init(jax.random.PRNGKey(0))
+batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)}
+ref, _ = jax.jit(m.loss)(params, batch)
+with jax.set_mesh(mesh):
+    s = jax.jit(make_pipeline_loss(cfg, mesh, n_micro=4, boundary="striped"))(params, batch)
+    d = jax.jit(make_pipeline_loss(cfg, mesh, n_micro=4, boundary="direct"))(params, batch)
+assert abs(float(s) - float(ref)) < 3e-2, (float(s), float(ref))
+assert abs(float(d) - float(ref)) < 3e-2, (float(d), float(ref))
+print("OK", float(s), float(d), float(ref))
+""", devices=4)
     assert "OK" in out

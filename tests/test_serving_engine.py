@@ -86,6 +86,37 @@ def test_prefill_right_alignment_batch_padding(setup):
     assert alone == mixed
 
 
+def test_prefill_logits_short_prompt_batched_matches_alone(setup):
+    """``prefill_logits`` of a left-padded short prompt equals its logits
+    alone to bf16 tolerance (the check chip_smoke.py makes at GPT-A
+    widths, where greedy tokens can flip on bf16 near-ties)."""
+    cfg, _, _, engine, _ = setup
+    short = Request(0, (np.arange(4) % cfg.vocab_size).astype(np.int32))
+    long = Request(1, (np.arange(12) * 5 % cfg.vocab_size).astype(np.int32))
+    batched, _ = engine.prefill_logits([short, long])
+    alone, _ = engine.prefill_logits([short])
+    assert batched.shape == (2, cfg.vocab_size)
+    np.testing.assert_allclose(np.asarray(batched[0]), np.asarray(alone[0]),
+                               atol=2e-2, rtol=2e-2)
+
+
+def test_dropped_engine_frees_its_weights_without_gc(setup):
+    """No reference cycle through the engine: dropping the last reference
+    releases it (and the device weights it holds) at once."""
+    import gc
+    import weakref
+
+    cfg, _, params, _, _ = setup
+    gc.disable()
+    try:
+        engine = ServingEngine(cfg, params, max_batch=2, max_len=16)
+        ref = weakref.ref(engine)
+        del engine
+        assert ref() is None
+    finally:
+        gc.enable()
+
+
 def test_ragged_prefill_masked_under_pallas_impl(setup):
     """The pallas flash kernel ignores positions; the engine must pin the
     masking sdpa for ragged batches so pad slots stay invisible even when

@@ -74,20 +74,43 @@ def test_sec67_compression_is_net_loss():
     assert comp > 1.3 * atlas  # paper: ~2× slowdown; direction must hold
 
 
-@pytest.mark.slow  # subprocess + full jit compile
 @pytest.mark.parametrize(
     "argv",
     [
+        # tier-1 guard: the train entry point on one device
         ["repro.launch.train", "--arch", "gpt-a", "--smoke", "--steps", "3",
          "--batch", "4", "--seq", "32", "--log-every", "1"],
-        ["repro.launch.serve", "--arch", "gpt-a", "--requests", "2",
-         "--max-new", "3", "--batch", "2"],
+        pytest.param(
+            ["repro.launch.serve", "--arch", "gpt-a", "--smoke", "--requests", "2",
+             "--max-new", "3", "--batch", "2"],
+            marks=pytest.mark.slow,  # subprocess + full jit compile
+        ),
     ],
 )
-def test_launcher_cli_smoke(argv):
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+def test_launcher_cli_smoke(argv, tmp_path):
+    # the compile cache goes where JAX_COMPILATION_CACHE_DIR says
+    cache = tmp_path / "jax_cache"
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_COMPILATION_CACHE_DIR=str(cache),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
     out = subprocess.run(
         [sys.executable, "-m", *argv], capture_output=True, text=True,
         env=env, timeout=600,
     )
     assert out.returncode == 0, out.stderr[-2000:]
+    assert any(cache.iterdir()), "no compile-cache entry written"
+
+
+def test_compile_cache_defaults_to_checkout():
+    """Without JAX_COMPILATION_CACHE_DIR the cache sits at a fixed path in
+    the checkout (a moving directory would never hit)."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    code = ("import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache(), jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    want = os.path.join(REPO, ".jax_cache")
+    assert out.stdout.split() == [want, want]
