@@ -112,6 +112,8 @@ def main(argv=None):
                       f"gnorm {float(metrics['grad_norm']):.3f} "
                       f"lr {float(metrics['lr']):.2e} "
                       f"tok/s {tokens_done/max(dt,1e-9):,.0f}", flush=True)
+            if step == 0:  # step 0 compiled, and reading its loss synced: start tok/s here
+                t0, tokens_done = time.time(), 0
             if ckpt and step and step % args.ckpt_every == 0:
                 ckpt.save(step, {"params": params, "opt": opt_state},
                           {"step": step, "loss": float(metrics["loss"])})

@@ -38,6 +38,7 @@ from repro.models.modules import (
     stack_layers,
 )
 from repro.parallel.sharding import constrain
+from repro.scopes import ATTN, EMBED, FFN, LOSS_HEAD
 from jax.sharding import PartitionSpec as P
 
 LOSS_CHUNK = 256  # sequence chunk for the big-vocab CE (memory bound)
@@ -90,18 +91,20 @@ def _block_apply(params, cfg: ModelConfig, x, positions, cache, gate=None):
     the pipeline's identity-padding for *shared* blocks whose weights are
     not themselves zero-padded (zamba2)."""
     g = 1.0 if gate is None else gate.astype(cfg.dtype)
-    h = rmsnorm(params["ln1"], x)
-    if cfg.mla is not None:
-        a, new_cache = attn.mla_apply(params["attn"], cfg, h, positions, cache)
-    else:
-        a, new_cache = attn.gqa_apply(params["attn"], cfg, h, positions, cache)
-    x = x + a * g
-    h = rmsnorm(params["ln2"], x)
-    if cfg.moe is not None:
-        f, aux = moe_lib.moe_apply(params["moe"], cfg, h)
-    else:
-        f, aux = ffn_apply(params["ffn"], h, cfg.ffn_activation), jnp.float32(0.0)
-    x = x + f * g
+    with jax.named_scope(ATTN):
+        h = rmsnorm(params["ln1"], x)
+        if cfg.mla is not None:
+            a, new_cache = attn.mla_apply(params["attn"], cfg, h, positions, cache)
+        else:
+            a, new_cache = attn.gqa_apply(params["attn"], cfg, h, positions, cache)
+        x = x + a * g
+    with jax.named_scope(FFN):
+        h = rmsnorm(params["ln2"], x)
+        if cfg.moe is not None:
+            f, aux = moe_lib.moe_apply(params["moe"], cfg, h)
+        else:
+            f, aux = ffn_apply(params["ffn"], h, cfg.ffn_activation), jnp.float32(0.0)
+        x = x + f * g
     x = constrain(x, P("data", None, None))
     return x, new_cache, aux
 
@@ -205,6 +208,7 @@ def build_model(cfg: ModelConfig) -> Model:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope(EMBED)
 def _embed_tokens(params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
     e = params["embed"]  # (V, d)
     return jnp.take(e, tokens, axis=0).astype(cfg.dtype)
@@ -216,6 +220,7 @@ def _head_weight(params, cfg: ModelConfig) -> jax.Array:
     return params["lm_head"]
 
 
+@jax.named_scope(LOSS_HEAD)
 def _lm_loss_chunked(cfg, x, w_head, labels, mask=None):
     """Next-token CE computed in sequence chunks to bound logits memory.
 

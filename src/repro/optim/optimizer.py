@@ -10,6 +10,8 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.scopes import OPTIMIZER
+
 Params = Any
 
 
@@ -143,7 +145,8 @@ def make_train_step(
             g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
             (grads, loss), _ = jax.lax.scan(acc_body, (g0, 0.0), batches)
             metrics = {}
-        params, opt_state, om = adamw_update(opt_cfg, grads, params, opt_state)
+        with jax.named_scope(OPTIMIZER):
+            params, opt_state, om = adamw_update(opt_cfg, grads, params, opt_state)
         metrics = {**metrics, **om, "loss": loss}
         return params, opt_state, metrics
 
