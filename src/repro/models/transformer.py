@@ -227,36 +227,68 @@ def _lm_loss_chunked(cfg, x, w_head, labels, mask=None):
     x (B,T,d) (already final-normed); labels (B,T) are the *targets at each
     position* (pre-shifted by the caller); mask (B,T) optional.
     """
-    B, T, d = x.shape
-    V = w_head.shape[-1]
-    chunk = min(LOSS_CHUNK, T)
-    Tpad = (-T) % chunk
-    if Tpad:
-        x = jnp.pad(x, ((0, 0), (0, Tpad), (0, 0)))
-        labels = jnp.pad(labels, ((0, 0), (0, Tpad)))
-        pad_mask = jnp.pad(
-            jnp.ones((B, T), jnp.float32) if mask is None else mask.astype(jnp.float32),
-            ((0, 0), (0, Tpad)),
-        )
-    else:
-        pad_mask = jnp.ones((B, T), jnp.float32) if mask is None else mask.astype(jnp.float32)
-    nc = x.shape[1] // chunk
-    xc = x.reshape(B, nc, chunk, d).transpose(1, 0, 2, 3)
-    lc = labels.reshape(B, nc, chunk).transpose(1, 0, 2)
-    mc = pad_mask.reshape(B, nc, chunk).transpose(1, 0, 2)
+    B, T, _ = x.shape
+    mask = jnp.ones((B, T), jnp.float32) if mask is None else mask.astype(jnp.float32)
+    # the head enters in f32, so its gradient leaves in f32; the astype's
+    # transpose casts it back to the head's own dtype
+    return _chunked_ce(x, w_head.astype(jnp.float32), labels, mask)
 
-    def body(carry, inp):
+
+def _to_chunks(a, chunk):
+    """(B, T, ...) -> (nc, B, chunk, ...), zero-padding T up to nc * chunk."""
+    B, T = a.shape[:2]
+    nc = -(-T // chunk)
+    a = jnp.pad(a, ((0, 0), (0, nc * chunk - T)) + ((0, 0),) * (a.ndim - 2))
+    return a.reshape(B, nc, chunk, *a.shape[2:]).swapaxes(0, 1)
+
+
+@jax.custom_vjp
+def _chunked_ce(x, w_head, labels, mask):
+    return _chunked_ce_fwd(x, w_head, labels, mask)[0]
+
+
+def _chunked_ce_fwd(x, w_head, labels, mask):
+    """The chunk scan also emits each chunk's logits cotangent in the compute
+    dtype, so the backward needs neither f32 logits nor a one-hot."""
+    T, V = x.shape[1], w_head.shape[-1]
+    chunk = min(LOSS_CHUNK, T)
+    xc = _to_chunks(x, chunk)
+    w_c = w_head.astype(x.dtype)
+    cnt = jnp.maximum(jnp.sum(mask), 1.0)
+
+    def body(tot, inp):
         xi, li, mi = inp
-        logits = jnp.einsum("btd,dv->btv", xi, w_head.astype(xi.dtype)).astype(jnp.float32)
+        logits = jnp.einsum("btd,dv->btv", xi, w_c).astype(jnp.float32)
         logits = constrain(logits, P("data", None, "model"))
         logz = jax.nn.logsumexp(logits, axis=-1)
         onehot = (jnp.arange(V, dtype=li.dtype)[None, None, :] == li[..., None])
         gold = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
-        nll = (logz - gold) * mi
-        return (carry[0] + jnp.sum(nll), carry[1] + jnp.sum(mi)), None
+        dlogits = (jnp.exp(logits - logz[..., None]) - onehot) * (mi / cnt)[..., None]
+        dlogits = constrain(dlogits.astype(x.dtype), P("data", None, "model"))
+        return tot + jnp.sum((logz - gold) * mi), dlogits
 
-    (tot, cnt), _ = jax.lax.scan(body, (jnp.float32(0.0), jnp.float32(0.0)), (xc, lc, mc))
-    return tot / jnp.maximum(cnt, 1.0)
+    tot, dlogits = jax.lax.scan(
+        body, jnp.float32(0.0), (xc, _to_chunks(labels, chunk), _to_chunks(mask, chunk))
+    )
+    return tot / cnt, (x, w_c, dlogits)
+
+
+def _chunked_ce_bwd(res, g):
+    """The head gradient is one matmul over every token, accumulated in f32,
+    not a per-chunk sum in the scan's carry. Padded rows are zero in both
+    operands, so they add nothing."""
+    x, w_c, dlogits = res
+    B, T, d = x.shape
+    nc, _, chunk, V = dlogits.shape
+    # batch-major token rows, so that a batch sharded over "data" stays so
+    dl = dlogits.swapaxes(0, 1).reshape(-1, V)
+    xt = jnp.pad(x, ((0, 0), (0, nc * chunk - T), (0, 0))).reshape(-1, d)
+    dx = g * jnp.einsum("kv,dv->kd", dl, w_c, preferred_element_type=jnp.float32)
+    dw = g * jnp.einsum("kd,kv->dv", xt, dl, preferred_element_type=jnp.float32)
+    return dx.reshape(B, nc * chunk, d)[:, :T].astype(x.dtype), dw, None, None
+
+
+_chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
 
 
 def _default_positions(tokens_shape, dtype=jnp.int32):
