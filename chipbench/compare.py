@@ -1,15 +1,21 @@
 """The numbers that decide ``correct``, each held against its limit from
 ``limits/<workload>.json``.
 
-Training compares two numbers with the plain reference:
+Training compares three numbers with the plain reference:
 
 * ``grad_gap``: the worst leaf's gap between the norm of the first
   gradient as the optimizer gets it (clipped) and the reference's;
+* ``grad_block_gap``: the same gap for the norms of that gradient's
+  row-major blocks, 64 a leaf (``weights.block_norms``).  One norm a
+  leaf is one projection of the gradient's error, which on some seeds
+  lies near zero on every leaf even for a float8 gradient; the worst of
+  many block norms does not (PERF.md);
 * ``change_gap``: the worst leaf's gap between the norm of the
   parameters' change over the first steps and the reference's.
 
 A leaf's gap is ``|program norm - reference norm|`` over the larger of
-the reference's norm of that leaf and of the median leaf.  Leaves whose
+the reference's norm of that leaf and of the median leaf (for blocks:
+of that block and of the median block).  Leaves whose
 reference gradient is under a thousandth of the median leaf's (nought to
 rounding, so Adam moves them by round-off) are left out of the change.
 
@@ -59,6 +65,7 @@ def train_numbers(prog: dict, ref: dict) -> Dict[str, float]:
     keep = moving_leaves(ref["grad_norms"])
     return {
         "grad_gap": worst_leaf_gap(prog["grad_norms"], ref["grad_norms"]),
+        "grad_block_gap": worst_leaf_gap(prog["grad_blocks"], ref["grad_blocks"]),
         "change_gap": worst_leaf_gap(prog["change_norms"], ref["change_norms"], keep),
     }
 

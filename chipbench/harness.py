@@ -1,16 +1,20 @@
 """Runs one cell of ``BENCHMARK.json`` and prints its result line.
 
 Everything a cell needs is found by name: its configuration file (the
-``file`` of its entry in ``configs``), its traffic mix
+``file`` of its entry in ``configs``), which names its plain reference
+(``reference/<reference>.py``) and its FLOP count (``<flops>.py``,
+``flops.py`` unless it says otherwise); its traffic mix
 (``traffic/<traffic>.json``, whose ``kind`` names the runner module
-``<kind>_cell.py``), its limits (``limits/<workload>.json``) and one
-reader per per-layer metric (``metrics/<metric>.py``).  A new cell, mix, configuration or metric is a
-new file and a new entry; no file here changes.
+``<kind>_cell.py``); its limits (``limits/<workload>.json``); and one
+reader per per-layer metric (``metrics/<metric>.py``).  A new cell, mix,
+configuration, reference, FLOP count or metric is a new file and a new
+entry; no file here changes.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import importlib
 import importlib.util
 import json
@@ -60,6 +64,8 @@ class Run:
     flops: float = 0.0  # model FLOPs done in the window
     trace: Optional[reduce.Trace] = None
     trace_window: Optional[tuple] = None  # (lo, hi) ns on the trace clock
+    # the optimized HLO text of the step the window drove (None: no step to map)
+    step_hlo: Optional[Callable[[], str]] = None
     extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
@@ -120,6 +126,16 @@ class Context:
         return dataclasses.replace(cfg, **over, dtype=jnp.dtype(self.sizes["dtype"]),
                                    param_dtype=jnp.dtype(self.sizes["param_dtype"]))
 
+    def reference(self):
+        """The configuration's plain reference, ``reference/<reference>.py``."""
+        return load_module(os.path.join(self.bench_dir, "reference",
+                                        f"{self.sizes['reference']}.py"))
+
+    def flops(self):
+        """The configuration's FLOP count, ``<flops>.py`` (``flops.py``
+        where the file names none)."""
+        return load_module(os.path.join(self.bench_dir, f"{self.sizes.get('flops', 'flops')}.py"))
+
     def window_begin(self) -> float:
         """Start the profiler (traced runs) and the window span; returns
         the window's start on the host clock."""
@@ -172,12 +188,18 @@ def free_device_arrays() -> None:
         a.delete()
 
 
-def _load_reader(bench_dir: str, name: str) -> Callable[[Run], Optional[float]]:
-    path = os.path.join(bench_dir, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
+@functools.lru_cache(maxsize=None)
+def load_module(path: str):
+    """The Python file at ``path`` as a module, loaded once per process."""
+    name = "chipbench_file_" + os.path.splitext(os.path.basename(path))[0].replace(".", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def _load_reader(bench_dir: str, name: str) -> Callable[[Run], Optional[float]]:
+    return load_module(os.path.join(bench_dir, "metrics", f"{name}.py")).read
 
 
 def _applies(metric: dict, workload: str, e2e_here: set) -> bool:
@@ -226,9 +248,14 @@ def result(ctx: Context, run: Run) -> dict:
     return line
 
 
+def runner_module(kind: str):
+    """``chipbench/<kind>_cell.py``: its ``run`` and what calibration reads."""
+    return importlib.import_module(f"chipbench.{kind}_cell")
+
+
 def runner(kind: str) -> Callable[[Context], Run]:
     """The ``run`` of ``chipbench/<kind>_cell.py``."""
-    return importlib.import_module(f"chipbench.{kind}_cell").run
+    return runner_module(kind).run
 
 
 def parse(argv: List[str]) -> argparse.Namespace:

@@ -116,6 +116,11 @@ class DenseLM:
         return jnp.sum(nll * mask) / jnp.sum(mask)
 
 
+def build(sizes: dict, precision: str = "f32") -> DenseLM:
+    """The reference model of a configuration file's sizes."""
+    return DenseLM(sizes, precision)
+
+
 # --------------------------------------------------------------------------
 # AdamW as the configuration states it
 # --------------------------------------------------------------------------

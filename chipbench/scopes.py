@@ -1,10 +1,13 @@
-"""Device time of the train step split by the program's named scopes.
+"""Device time of a step split by the program's named scopes.
 
-The program names five ``jax.named_scope``s on its train path (``embed``,
-``attn``, ``ffn``, ``loss_head``, ``optimizer``); each reaches every
-instruction of the optimized HLO as part of its ``op_name`` metadata.  The
-profiler names a device op by its HLO instruction, so the compiled step's
-text maps each op of the trace to a scope:
+The program declares its ``jax.named_scope`` names in ``repro/scopes.py``
+(``declared``: ``ALL``, or ``SCOPES`` where it has no ``ALL``; today
+``embed``, ``attn``, ``ffn``, ``loss_head``, ``optimizer``); each
+reaches every instruction of the optimized HLO as part of its
+``op_name`` metadata.  The profiler names a device op by its
+HLO instruction, so the compiled step's text maps each op of the trace to
+the innermost declared scope around it, and a scope that a later program
+declares, inside another or not, is mapped with no change here:
 
 - ``op_scopes`` reads that text into ``{instruction: scope}``;
 - ``self_ns`` splits the device time of a trace's window by scope, each
@@ -14,16 +17,17 @@ text maps each op of the trace to a scope:
 - ``scope_ms`` is what the ``device_<scope>_ms.train`` readers return.
 
 The map is made after the window of a traced run, so neither ``setup_s``
-nor the window pays for it: the runner's own step is built again from the
-run's sizes and mix, compiled with abstract arguments, and its text read.
+nor the window pays for it, from the run's ``step_hlo``: the runner builds
+its step again, compiles it with abstract arguments and returns its text.
+A run without a trace or without a step to map reads nothing.
 """
 from __future__ import annotations
 
+import functools
 import re
 import time
 from typing import Dict, Iterable, Optional, Tuple
 
-SCOPES = ("embed", "attn", "ffn", "loss_head", "optimizer")
 UNSCOPED = "unscoped"
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
@@ -31,12 +35,23 @@ _OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
 _OP_KEY = re.compile(r"%?([\w.\-]+)")
 
 
+@functools.lru_cache(maxsize=None)
+def declared() -> Tuple[str, ...]:
+    """Every scope name the program declares: ``repro.scopes.ALL`` where
+    the module has it (the layer scopes and any sub-scope, such as a
+    ``router`` inside ``ffn``), else its layer scopes ``SCOPES``."""
+    from repro import scopes
+
+    return tuple(getattr(scopes, "ALL", scopes.SCOPES))
+
+
 def scope_of(path: str) -> str:
     """The innermost scope named in an ``op_name`` path: its last segment
-    that is a scope name (``transpose(jvp(attn))`` gives ``attn``; an
-    argument's path, ``params['ffn']``, gives none)."""
+    that is a declared scope name (``transpose(jvp(attn))`` gives
+    ``attn``; an argument's path, ``params['ffn']``, gives none)."""
+    names = declared()
     for token in reversed(re.split(r"[/(),]", path)):
-        if token in SCOPES:
+        if token in names:
             return token
     return UNSCOPED
 
@@ -104,57 +119,23 @@ def self_ns(trace, op_scope: Dict[str, str], lo: float, hi: float) -> Dict[str, 
     """Device self time in ``[lo, hi]`` per scope (and ``unscoped``),
     mean over the trace's devices; an op missing from ``op_scope`` is
     ``unscoped``."""
-    acc = dict.fromkeys(SCOPES + (UNSCOPED,), 0.0)
+    acc = dict.fromkeys(declared() + (UNSCOPED,), 0.0)
     for key, ns in instruction_self_ns(trace, lo, hi).items():
         acc[op_scope.get(key, UNSCOPED)] += ns
     return acc
 
 
-class _Cell:
-    """What ``train_cell.Program`` reads of a ``Context``, from a ``Run``."""
-
-    def __init__(self, run):
-        self.sizes, self.mix, self.chips, self.seed = run.sizes, run.mix, run.chips, 0
-
-    def program_config(self):
-        from chipbench.harness import Context
-
-        return Context.program_config(self)
-
-
-def step_hlo(run) -> str:
-    """The optimized HLO text of the runner's train step, compiled with
-    abstract arguments as the runner's sharded state and batch."""
-    import jax
-
-    from chipbench import traffic, train_cell
-    from repro.optim.optimizer import init_opt_state
-    from repro.parallel.sharding import make_batch_shardings, make_param_shardings
-
-    prog = train_cell.Program(_Cell(run))
-
-    def abstract(shapes, shardings):
-        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-                            shapes, shardings)
-
-    with jax.set_mesh(prog.mesh):
-        params = abstract(prog.shapes, make_param_shardings(prog.shapes, prog.mesh))
-        opt_state = jax.eval_shape(init_opt_state, params)
-        batch = jax.eval_shape(lambda: traffic.train_batch(run.mix, prog.cfg.vocab_size, 0, 0))
-        batch = abstract(batch, make_batch_shardings(batch, prog.mesh))
-        return prog.step.lower(params, opt_state, batch).compile().as_text()
-
-
 def run_op_scopes(run) -> Optional[Dict[str, str]]:
-    """The op map of a traced train run, made once and kept on the run."""
-    if (run.kind != "train" or run.trace is None or run.trace_window is None
+    """The op map of a traced run with a step to map, made once and kept
+    on the run."""
+    if (run.step_hlo is None or run.trace is None or run.trace_window is None
             or not run.trace.devices):
         return None
     if "op_scopes" not in run.extra:
         from chipbench.harness import log
 
         t0 = time.perf_counter()
-        run.extra["op_scopes"] = op_scopes(step_hlo(run))
+        run.extra["op_scopes"] = op_scopes(run.step_hlo())
         log(f"op scope map: {len(run.extra['op_scopes'])} instructions in "
             f"{time.perf_counter() - t0:.3f} s after the window")
     return run.extra["op_scopes"]

@@ -1,7 +1,8 @@
 """The harness end to end without a chip, at a size a CPU test holds:
-every cell of BENCHMARK.json loads and runs by name, a new cell, mix,
-configuration and metric are new files plus new entries, and a timed
-path broken underneath makes ``correct`` come out false."""
+every cell of BENCHMARK.json loads and runs by name; a new cell, mix,
+configuration, reference, FLOP count and metric are new files plus new
+entries, shown with a pipelined four-chip cell on four virtual devices;
+and a timed path broken underneath makes ``correct`` come out false."""
 import json
 import os
 import shutil
@@ -45,7 +46,8 @@ def test_every_cell_loads_by_name(workload):
     sizes = {k: v for k, v in ctx.sizes.items() if isinstance(v, int) and hasattr(cfg, k)}
     assert all(getattr(cfg, k) == v for k, v in sizes.items())
     assert callable(harness.runner(ctx.mix["kind"]))
-    assert set(ctx.limits) == {"grad_gap", "change_gap"}
+    assert callable(ctx.reference().build) and callable(ctx.flops().train_step_flops)
+    assert set(ctx.limits) == {"grad_gap", "grad_block_gap", "change_gap"}
     for m in ctx.bench["per_layer"]:
         assert callable(harness._load_reader(BENCH_DIR, m["name"]))
 
@@ -67,45 +69,95 @@ def test_traced_run_reports_per_layer_metrics(root):
     assert {"busy_s", "window_s"} <= set(line["device"])
 
 
-def test_new_cell_mix_config_and_metric_are_files_plus_entries(tmp_path):
-    root = tiny.write_root(str(tmp_path))
+FOUR_DEVICES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "four_devices.py")
+PIPELINED = "gpt-a-4l-pp2tp2.train"
+TINY_FLOPS = '''"""A new family's FLOP count, as a test states it: twice the dense one."""
+from chipbench import flops
+
+
+def train_step_flops(sizes, batch, seq_len):
+    return 2 * flops.train_step_flops(sizes, batch, seq_len)
+'''
+TINY_REFERENCE = '''"""A new family's reference, as a test states it: the dense one, which
+says on stderr that it was built."""
+import sys
+
+from chipbench.reference.dense_lm import DenseLM, make_train_step, zeros_like_tree  # noqa: F401
+
+
+def build(sizes, precision="f32"):
+    print("built reference dense_tiny", file=sys.stderr)
+    return DenseLM(sizes, precision)
+'''
+
+
+def write(path, text):
+    with open(path, "w") as f:
+        f.write(text if isinstance(text, str) else json.dumps(text))
+
+
+@pytest.fixture(scope="module")
+def pipelined_root(tmp_path_factory):
+    """A root with one more cell, made of new files and entries alone: the
+    committed pipelined mix and four-layer configuration at CPU size, on
+    four chips; the configuration names a FLOP count and a reference of
+    its own; two new metrics."""
+    root = tiny.write_root(str(tmp_path_factory.mktemp("bench")))
     cb = os.path.join(root, "chipbench")
-    with open(os.path.join(cb, "configs", "gpt-a-2l.json")) as f:
-        cfg = json.load(f)
-    cfg["num_layers"] = 4
-    with open(os.path.join(cb, "configs", "gpt-a-4l.json"), "w") as f:
-        json.dump(cfg, f)
-    with open(os.path.join(cb, "traffic", "train.json")) as f:
-        mix = json.load(f)
-    mix.update(seq_len=128, batch=1)
-    with open(os.path.join(cb, "traffic", "train-long.json"), "w") as f:
-        json.dump(mix, f)
+    cfg = tiny._load(BENCH_DIR, "configs", "gpt-a-4l.json")
+    cfg.update(tiny.SIZES, num_layers=4, flops="flops_tiny", reference="dense_tiny")
+    write(os.path.join(cb, "configs", "gpt-a-4l.json"), cfg)
+    mix = tiny._load(BENCH_DIR, "traffic", "train_pp2tp2.json")
+    mix.update(seq_len=64)  # batch 4: the mix's 4 microbatches of one row
+    write(os.path.join(cb, "traffic", "train_pp2tp2.json"), mix)
     shutil.copy(os.path.join(cb, "limits", "gpt-a-2l.train.json"),
-                os.path.join(cb, "limits", "gpt-a-4l.train-long.json"))
-    metrics = os.path.join(cb, "metrics")
-    os.unlink(metrics)
-    shutil.copytree(os.path.join(BENCH_DIR, "metrics"), metrics)
-    with open(os.path.join(metrics, "steps_done.train.py"), "w") as f:
-        f.write("def read(run):\n    return run.attempted\n")
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        b = json.load(f)
+                os.path.join(cb, "limits", f"{PIPELINED}.json"))
+    for sub in ("metrics", "reference"):
+        os.unlink(os.path.join(cb, sub))
+        shutil.copytree(os.path.join(BENCH_DIR, sub), os.path.join(cb, sub))
+    write(os.path.join(cb, "metrics", "steps_done.train.py"),
+          "def read(run):\n    return run.attempted\n")
+    write(os.path.join(cb, "metrics", "flops_per_step.train.py"),
+          "def read(run):\n    return run.flops / run.attempted\n")
+    write(os.path.join(cb, "reference", "dense_tiny.py"), TINY_REFERENCE)
+    write(os.path.join(cb, "flops_tiny.py"), TINY_FLOPS)
+    b = tiny._load(root, "BENCHMARK.json")
     b["configs"].append({"name": "gpt-a-4l", "source": "https://arxiv.org/abs/2411.14458",
                          "file": "chipbench/configs/gpt-a-4l.json",
                          "reduced": ["num_layers"], "why": "test"})
-    b["workloads"].append({"name": "gpt-a-4l.train-long", "config": "gpt-a-4l",
-                           "traffic": "train-long", "chips": 1, "why": "test"})
+    b["workloads"].append({"name": PIPELINED, "config": "gpt-a-4l",
+                           "traffic": "train_pp2tp2", "chips": 4, "why": "test"})
     for m in b["end_to_end"] + b["per_layer"]:
         if "workloads" in m:
-            m["workloads"].append("gpt-a-4l.train-long")
-    b["per_layer"].append({"name": "steps_done.train", "unit": "steps",
-                           "better": "higher", "source": "host_clock", "layer": "benchmark client",
-                           "moves": "train_tokens_per_s", "workloads": ["gpt-a-4l.train-long"]})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(b, f)
-    line = run(root, "gpt-a-4l.train-long", trace=1)
+            m["workloads"].append(PIPELINED)
+    for name, unit in (("steps_done.train", "steps"), ("flops_per_step.train", "FLOP"),
+                       ("exposed_collective.train", "%")):
+        b["per_layer"].append({"name": name, "unit": unit, "better": "higher",
+                               "source": "host_clock", "layer": "benchmark client",
+                               "moves": "train_tokens_per_s", "workloads": [PIPELINED]})
+    write(os.path.join(root, "BENCHMARK.json"), b)
+    return root
+
+
+def run_on_four(root, workload, trace=0, fault=None):
+    argv = [sys.executable, FOUR_DEVICES, root] + (["--fault", fault] if fault else []) + [
+        "--workload", workload, "--seed", str(SEED), "--seconds", "1", "--trace", str(trace)]
+    p = subprocess.run(argv, capture_output=True, text=True, timeout=600, cwd=root)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1]), p.stderr
+
+
+def test_new_cell_mix_config_and_metric_are_files_plus_entries(pipelined_root):
+    from chipbench import flops
+
+    line, err = run_on_four(pipelined_root, PIPELINED, trace=1)
     assert line["correct"], line["checks"]
-    assert line["metrics"]["steps_done.train"]["value"] == line["attempted"]
+    assert line["device"]["count"] == 4
+    assert line["metrics"]["steps_done.train"]["value"] == line["attempted"] > 0
+    cfg = tiny._load(pipelined_root, "chipbench", "configs", "gpt-a-4l.json")
+    assert line["metrics"]["flops_per_step.train"]["value"] == 2 * flops.train_step_flops(cfg, 4, 64)
     assert "mfu.train" in line["metrics"]
+    assert "built reference dense_tiny" in err
 
 
 # ---- the timed path broken underneath: correct comes out false ----------
@@ -147,6 +199,21 @@ def test_fault_half_batch_left_out(root, monkeypatch):
 
     monkeypatch.setattr(transformer, "build_model", halved)
     line = run(root, "gpt-a-2l.train")
+    assert not line["correct"], line["checks"]
+
+
+def test_map_of_the_pipelined_step_is_the_windows(pipelined_root):
+    """The pipelined step returns its layers sharded over ``pod``, so the
+    window runs another program than the first step; the op map is of the
+    window's."""
+    p = subprocess.run([sys.executable, FOUR_DEVICES, pipelined_root, "--map", PIPELINED],
+                       capture_output=True, text=True, timeout=600, cwd=pipelined_root)
+    assert p.returncode == 0, p.stderr[-3000:]
+    assert json.loads(p.stdout.strip().splitlines()[-1]) == {"same_map": True}
+
+
+def test_fault_exchange_between_chips_left_out(pipelined_root):
+    line, _ = run_on_four(pipelined_root, PIPELINED, fault="exchange")
     assert not line["correct"], line["checks"]
 
 

@@ -34,6 +34,18 @@ def test_collective_exposure(trace):
     assert reduce.exposed_collective_share(compute_only, 0, 100) is None
 
 
+def test_exposed_collective_reader(trace):
+    from chipbench import harness
+
+    read = harness._load_reader(harness.BENCH_DIR, "exposed_collective.train")
+    run = harness.Run(kind="train", chips=2, peak={}, sizes={}, mix={}, metrics={}, numbers={},
+                      attempted=1, failed=0, memory_peak_bytes=0, window_s=1.0, trace=trace,
+                      trace_window=(0, 1000))
+    assert read(run) == pytest.approx(100 * (70 + 400) / 2 / 1000)
+    run.trace = reduce.Trace({"/device:TPU:0": [(0, 10, "fusion.1")]}, [])
+    assert read(run) is None
+
+
 def test_idle_gaps_are_named_by_the_covering_span(trace):
     gaps = reduce.idle_gaps(trace, 0, 1000)
     # TPU:0 gaps: [0,100] batch(90)/none, [400,500] dispatch, [600,800] sync, [900,1000] wait

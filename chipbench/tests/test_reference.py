@@ -4,12 +4,13 @@ float32 rounding; in bfloat16, as configured, to bfloat16 rounding."""
 import dataclasses
 
 import jax
+import numpy as np
 import jax.numpy as jnp
 import pytest
 
 from chipbench import traffic
 from chipbench.reference import dense_lm
-from chipbench.weights import Weights
+from chipbench.weights import Weights, _num_blocks, block_norms
 
 SIZES = {"num_layers": 2, "d_model": 128, "num_heads": 4, "num_kv_heads": 4, "head_dim": 32,
          "d_ff": 512, "vocab_size": 512, "ffn_activation": "gelu", "rope_theta": 10000.0,
@@ -65,3 +66,17 @@ def test_adamw_step_moves_every_matrix_by_about_lr(setup):
     w = p1["layers"]["ffn"]["w_up"] - params["layers"]["ffn"]["w_up"]
     assert float(jnp.max(jnp.abs(w))) == pytest.approx(1e-3, rel=1e-3)  # sign(g) * lr
 
+
+
+def test_block_norms_are_row_major_blocks_of_each_leaf():
+    rows = np.arange(2048 * 2048, dtype=np.float64).reshape(2048, 2048) / 2**20
+    tree = {"a": jnp.asarray(rows, jnp.float32), "b": jnp.ones((3,), jnp.bfloat16)}
+    got = np.asarray(block_norms(tree))
+    # 2^22 elements: 4 blocks of 512 whole rows (2^20 each); 3 elements: one block
+    want = np.concatenate([np.linalg.norm(rows.reshape(4, -1), axis=1), [np.sqrt(3.0)]])
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
+def test_blocks_at_gpt_a_widths():
+    d, ff, V, L = 4096, 16384, 50304, 2
+    assert [_num_blocks(n) for n in (V * d, L * d * d, L * d * ff, L * d, d)] == [64, 32, 64, 1, 1]

@@ -1,5 +1,6 @@
 """Device time split by the program's named scopes: the HLO map, self
-time on a recorded trace in which a ``while`` encloses two ops, and the
+time on a recorded trace in which a ``while`` encloses two ops, a scope
+that the program declares inside another, and the
 ``device_<scope>_ms.train`` readers."""
 import os
 import random
@@ -11,7 +12,7 @@ import tiny
 from chipbench import harness, reduce, scopes
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-READERS = [f"device_{s}_ms.train" for s in scopes.SCOPES + (scopes.UNSCOPED,)]
+READERS = [f"device_{s}_ms.train" for s in scopes.declared() + (scopes.UNSCOPED,)]
 
 HLO = """HloModule jit_train_step, is_scheduled=true
 
@@ -40,9 +41,10 @@ def trace():
 
 
 def test_scope_names_are_the_programs():
-    from repro.scopes import SCOPES
+    import repro.scopes
 
-    assert scopes.SCOPES == SCOPES
+    assert scopes.declared() == tuple(getattr(repro.scopes, "ALL", repro.scopes.SCOPES))
+    assert set(repro.scopes.SCOPES) <= set(scopes.declared())
 
 
 def test_op_scopes_reads_the_innermost_scope_of_each_instruction():
@@ -86,6 +88,46 @@ def test_self_time_sums_to_busy_time_on_any_overlap():
             ((s, e) for s, e, _ in ops), lo, hi))))
 
 
+@pytest.fixture
+def router_declared(monkeypatch):
+    """The program declaring one more scope, ``router``, which the model
+    would open inside ``ffn``."""
+    import repro.scopes
+
+    monkeypatch.setattr(repro.scopes, "ALL", repro.scopes.SCOPES + ("router",), raising=False)
+    scopes.declared.cache_clear()
+    yield
+    monkeypatch.undo()
+    scopes.declared.cache_clear()
+
+
+def test_a_declared_sub_scope_is_mapped_innermost(router_declared, trace):
+    assert scopes.declared()[-1] == "router"
+    hlo = HLO.replace("/ffn/dot_general", "/ffn/router/dot_general")
+    op_scope = scopes.op_scopes(hlo)
+    assert op_scope == dict(MAP, **{"convolution.4": "router"})
+    assert scopes.scope_of("jit(train_step)/transpose(jvp(ffn))/router/dot") == "router"
+    assert scopes.scope_of("jit(train_step)/jvp(router)/ffn/dot") == "ffn"
+    by = scopes.self_ns(trace, op_scope, 0, 1000)
+    assert sum(by.values()) == pytest.approx(reduce.busy_ns(trace, 0, 1000))
+    assert by["router"] == pytest.approx((200 + 380) / 2) and by["ffn"] == 0
+
+
+def test_a_constant_that_is_not_in_all_is_no_scope(monkeypatch):
+    """Only ``ALL`` (or ``SCOPES``) declares: another upper-case constant
+    of the module names no scope."""
+    import repro.scopes
+
+    monkeypatch.setattr(repro.scopes, "ROUTER", "router", raising=False)
+    scopes.declared.cache_clear()
+    try:
+        assert "router" not in scopes.declared()
+        assert scopes.scope_of("jit(train_step)/ffn/router/dot") == "ffn"
+    finally:
+        monkeypatch.undo()
+        scopes.declared.cache_clear()
+
+
 @pytest.fixture(scope="module")
 def ctx(tmp_path_factory):
     root = tiny.write_root(str(tmp_path_factory.mktemp("bench")))
@@ -93,10 +135,10 @@ def ctx(tmp_path_factory):
                            root=root, require_chip=False)
 
 
-def tiny_run(ctx, trace=None, window=None):
+def tiny_run(ctx, trace=None, window=None, step_hlo=None):
     return harness.Run(kind="train", chips=1, peak=ctx.peak, sizes=ctx.sizes, mix=ctx.mix,
                        metrics={}, numbers={}, attempted=4, failed=0, memory_peak_bytes=0,
-                       window_s=1.0, trace=trace, trace_window=window)
+                       window_s=1.0, trace=trace, trace_window=window, step_hlo=step_hlo)
 
 
 def read_all(run):
@@ -107,35 +149,38 @@ def test_readers_give_none_without_a_trace(ctx):
     assert set(read_all(tiny_run(ctx)).values()) == {None}
     no_device = reduce.Trace({}, [(0, 1000, reduce.WINDOW_SPAN)])
     assert set(read_all(tiny_run(ctx, no_device, (0, 1000))).values()) == {None}
+    # a trace, but no step to map
+    ops = reduce.Trace({"/device:TPU:0": [(0, 100, "fusion.1")]}, [(0, 1000, reduce.WINDOW_SPAN)])
+    assert set(read_all(tiny_run(ctx, ops, (0, 1000))).values()) == {None}
 
 
 def test_map_of_the_runners_step(ctx):
-    """The map made from abstract arguments is the one of the step the
-    runner drives with its real state and batch."""
+    """The map made from abstract arguments is the one of the step that
+    the window drives with the state set-up hands it and a real batch."""
     import jax
 
     from chipbench import train_cell
-    from repro.optim.optimizer import init_opt_state
 
     prog = train_cell.Program(ctx)
     with jax.set_mesh(prog.mesh):
-        params = prog.weights.make(ctx.seed)
-        real = prog.step.lower(params, init_opt_state(params), prog.put(next(prog.feed)))
+        params, opt_state, _ = prog.first_steps()
+        real = prog.step.lower(params, opt_state, prog.put(next(prog.feed)))
         real = scopes.op_scopes(real.compile().as_text())
-    run = tiny_run(ctx, reduce.Trace({"/device:TPU:0": []}, []), (0, 1000))
+    run = tiny_run(ctx, reduce.Trace({"/device:TPU:0": []}, []), (0, 1000), prog.step_hlo)
     assert scopes.run_op_scopes(run) == real
-    assert set(real.values()) == set(scopes.SCOPES) | {scopes.UNSCOPED}
+    assert set(real.values()) == set(scopes.declared()) | {scopes.UNSCOPED}
 
     # one op of each scope, 100 ns each, with one op the map lacks
-    names = [next(k for k, v in real.items() if v == s) for s in scopes.SCOPES]
+    names = [next(k for k, v in real.items() if v == s) for s in scopes.declared()]
     events = [(100 * i, 100 * i + 100, n) for i, n in enumerate(names + ["unknown.1"])]
-    run = tiny_run(ctx, reduce.Trace({"/device:TPU:0": events}, []), (0, 1000))
+    run = tiny_run(ctx, reduce.Trace({"/device:TPU:0": events}, []), (0, 1000), prog.step_hlo)
     run.extra["op_scopes"] = real
     got = read_all(run)
     assert got == {m: pytest.approx(100 / 1e6 / 4) for m in READERS}
 
 
 def test_readers_give_none_for_a_program_without_scopes(ctx):
-    run = tiny_run(ctx, reduce.Trace({"/device:TPU:0": [(0, 100, "fusion.1")]}, []), (0, 1000))
+    run = tiny_run(ctx, reduce.Trace({"/device:TPU:0": [(0, 100, "fusion.1")]}, []), (0, 1000),
+                   lambda: "")
     run.extra["op_scopes"] = {"fusion.1": scopes.UNSCOPED}
     assert set(read_all(run).values()) == {None}

@@ -1,6 +1,7 @@
 """A benchmark root at a size a CPU test holds: the real cells' mixes and
 configuration with small widths, written under a temporary directory, so
-that the harness runs end to end without a chip."""
+that the harness runs end to end without a chip.  Its metric readers,
+references and FLOP count are the committed files."""
 import json
 import os
 import shutil
@@ -23,7 +24,8 @@ def write_root(tmp, sizes=None, seq_len=64, batch=2):
     bench = _load(REPO_ROOT, "BENCHMARK.json")
     for sub in ("configs", "traffic", "limits"):
         os.makedirs(os.path.join(tmp, "chipbench", sub), exist_ok=True)
-    os.symlink(os.path.join(BENCH_DIR, "metrics"), os.path.join(tmp, "chipbench", "metrics"))
+    for name in ("metrics", "reference", "flops.py"):
+        os.symlink(os.path.join(BENCH_DIR, name), os.path.join(tmp, "chipbench", name))
     for c in bench["configs"]:
         cfg = _load(REPO_ROOT, c["file"])
         cfg.update(sizes or SIZES)

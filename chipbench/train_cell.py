@@ -1,31 +1,57 @@
 """Runner of ``train`` mixes: a closed loop of the program's train step.
 
 The step is built as ``launch/train.py`` builds it: ``make_train_step``
-over ``model.loss``, jitted with the parameters and optimizer state
-donated, on the host mesh.  Set-up makes the weights from the seed, then drives that
-same step object through the mix's first ``check_steps`` steps with the
-feed the window uses; their losses, the first gradient (read from Adam's
+over ``model.loss`` on the host mesh or, where the mix holds
+``"pipeline": {"microbatches": n, "boundary": "striped"|"direct"}``,
+over ``make_pipeline_loss`` on the host's two-pod mesh; jitted with the
+parameters and optimizer state donated.  Set-up makes the weights from
+the seed, then drives that same step object through the mix's first
+``check_steps`` steps with the feed the window uses; their losses, the first gradient (read from Adam's
 first moment after one step) and the parameters' change after them are
 the program's readings.  The window continues the same object, with at
 most two steps in flight, and ends when the last step dispatched in it is
 done.  Once the window has closed and the program's state is freed, the
-plain reference follows the same first steps from the same seed.
+plain reference that the configuration names follows the same first
+steps from the same seed, in float32, on no pipeline, with its
+parameters, moments and tokens placed on the program's mesh as the
+program places its own.
 """
 from __future__ import annotations
 
 import collections
-import importlib
 import time
 from typing import Dict
 
 import numpy as np
 
-from chipbench import compare, flops, traffic
+from chipbench import compare, traffic
 from chipbench.harness import Context, Run, free_device_arrays, log, memory_peak, span
-from chipbench.weights import Weights, leaf_norms
+from chipbench.weights import Weights, block_norms, leaf_norms
 
 OPT_KEYS = ("peak_lr", "min_lr_ratio", "warmup_steps", "total_steps", "b1", "b2",
             "eps", "weight_decay", "clip_norm")
+
+
+def _abstract(tree, shardings=None):
+    """``tree``'s shapes and dtypes with the shardings of its arrays, or
+    with ``shardings``."""
+    import jax
+
+    if shardings is None:
+        shardings = jax.tree.map(lambda x: x.sharding, tree)
+    return jax.tree.map(lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+                        tree, shardings)
+
+
+def make_mesh(ctx: Context):
+    """The program's host mesh for the cell: two pods where the mix is
+    pipelined, and as many devices as the cell has chips."""
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(multi_pod="pipeline" in ctx.mix)
+    if mesh.size != ctx.chips:
+        raise RuntimeError(f"mesh {dict(mesh.shape)} is not the cell's {ctx.chips} chips")
+    return mesh
 
 
 class Program:
@@ -34,19 +60,24 @@ class Program:
     def __init__(self, ctx: Context):
         import jax
 
-        from repro.launch.mesh import make_host_mesh
         from repro.models.transformer import build_model
         from repro.optim.optimizer import OptimizerConfig, make_train_step
+        from repro.parallel.pipeline import make_pipeline_loss
         from repro.parallel.sharding import make_batch_shardings, make_param_shardings
 
         self.ctx, self.mix = ctx, ctx.mix
         self.cfg = ctx.program_config()
         model = build_model(self.cfg)
-        self.mesh = make_host_mesh()
-        if self.mesh.size != ctx.chips:
-            raise RuntimeError(f"mesh {dict(self.mesh.shape)} is not the cell's {ctx.chips} chips")
+        self.mesh = make_mesh(ctx)
         self.opt = OptimizerConfig(**{k: self.mix["optimizer"][k] for k in OPT_KEYS})
-        self.step = jax.jit(make_train_step(model.loss, self.opt), donate_argnums=(0, 1))
+        pipe = self.mix.get("pipeline")
+        if pipe:
+            loss = make_pipeline_loss(self.cfg, self.mesh, n_micro=pipe["microbatches"],
+                                      boundary=pipe["boundary"])
+            step = make_train_step(loss, self.opt, loss_has_metrics=False)
+        else:
+            step = make_train_step(model.loss, self.opt)
+        self.step = jax.jit(step, donate_argnums=(0, 1))
         with jax.set_mesh(self.mesh):
             self.shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
             self.weights = Weights(self.shapes, make_param_shardings(self.shapes, self.mesh))
@@ -72,9 +103,26 @@ class Program:
             losses.append(m["loss"])
             if s == 0:  # Adam's first moment after one step is (1 - b1) g
                 grad_norms = np.asarray(leaf_norms(opt_state.mu)) / (1.0 - self.opt.b1)
+                grad_blocks = np.asarray(block_norms(opt_state.mu)) / (1.0 - self.opt.b1)
         readings = {"losses": [float(x) for x in losses], "grad_norms": grad_norms,
+                    "grad_blocks": grad_blocks,
                     "change_norms": self.weights.change_norms(params, self.ctx.seed)}
+        self.window_state = _abstract((params, opt_state))
         return params, opt_state, readings
+
+    def step_hlo(self) -> str:
+        """The optimized HLO text of the step as the window calls it,
+        compiled again from the shapes and shardings of the state that
+        set-up hands to the window.  A step may return its state sharded
+        otherwise than it took it (the pipelined step puts each stage's
+        layers on its pod), so the window can run another program than
+        the first step did."""
+        import jax
+
+        batch = jax.eval_shape(lambda: traffic.train_batch(self.mix, self.cfg.vocab_size, 0, 0))
+        batch = _abstract(batch, self._batch_shardings(batch, self.mesh))
+        with jax.set_mesh(self.mesh):
+            return self.step.lower(*self.window_state, batch).compile().as_text()
 
     def window(self, params, opt_state, t_end: float):
         import jax
@@ -106,24 +154,35 @@ def reference_readings(ctx: Context, shapes, precision: str = "f32",
     import jax
     import jax.numpy as jnp
 
-    ref = importlib.import_module(f"chipbench.reference.{ctx.sizes['reference']}")
+    from repro.parallel.sharding import make_batch_shardings, make_param_shardings
+
+    ref = ctx.reference()
     mix, V = ctx.mix, ctx.sizes["vocab_size"]
-    model = ref.DenseLM(ctx.sizes, precision)
-    step = ref.make_train_step(model, mix["optimizer"])
-    weights = Weights(shapes)
+    step = ref.make_train_step(ref.build(ctx.sizes, precision), mix["optimizer"])
+    mesh = make_mesh(ctx)
+    p_sh = make_param_shardings(shapes, mesh)
+    weights = Weights(shapes, p_sh)
     p = weights.make(ctx.seed)
-    mu, nu = ref.zeros_like_tree(p), ref.zeros_like_tree(p)
+    zeros = jax.jit(ref.zeros_like_tree, out_shardings=p_sh)
+    mu, nu = zeros(p), zeros(p)
     B, T = mix["batch"], mix["seq_len"]
     keep = int(round(token_share * (T - 1)))
-    mask = jnp.zeros((B, T - 1), jnp.float32).at[:, :keep].set(1.0)
-    losses, first = [], None
+
+    def put(x):
+        return jax.device_put(x, make_batch_shardings(jax.eval_shape(lambda: x), mesh))
+
+    mask = np.zeros((B, T - 1), np.float32)
+    mask[:, :keep] = 1.0
+    mask = put(mask)
+    losses, first, blocks = [], None, None
     for s in range(mix["check_steps"]):
-        tokens = jnp.asarray(traffic.train_batch(mix, V, ctx.seed, s)["tokens"])
+        tokens = put(traffic.train_batch(mix, V, ctx.seed, s)["tokens"])
         p, mu, nu, loss, gn = step(p, mu, nu, jnp.int32(s + 1), tokens, mask)
         losses.append(loss)
-        if s == 0:
+        if s == 0:  # as the program's: Adam's first moment is (1 - b1) g
             first = np.asarray(gn)
-    out = {"losses": [float(x) for x in losses], "grad_norms": first,
+            blocks = np.asarray(block_norms(mu)) / (1.0 - mix["optimizer"]["b1"])
+    out = {"losses": [float(x) for x in losses], "grad_norms": first, "grad_blocks": blocks,
            "change_norms": weights.change_norms(p, ctx.seed)}
     del p, mu, nu
     free_device_arrays()
@@ -148,7 +207,6 @@ def run(ctx: Context) -> Run:
     del params, opt_state
     free_device_arrays()
     shapes = prog.shapes
-    del prog
     t_ref = time.perf_counter()
     ref = reference_readings(ctx, shapes)
     log(f"reference {time.perf_counter() - t_ref:.3f} s; readings {ref}; loss_gap (not "
@@ -159,8 +217,9 @@ def run(ctx: Context) -> Run:
                        "setup_s": (t0 - ctx.t_start, "s")},
               numbers=compare.train_numbers(readings, ref),
               attempted=steps, failed=0, memory_peak_bytes=mem, window_s=t1 - t0,
-              flops=steps * flops.train_step_flops(ctx.sizes, mix["batch"], mix["seq_len"]),
-              trace=trace, extra={"program": readings, "reference": ref, "shapes": shapes})
+              flops=steps * ctx.flops().train_step_flops(ctx.sizes, mix["batch"], mix["seq_len"]),
+              trace=trace, step_hlo=prog.step_hlo,
+              extra={"program": readings, "reference": ref, "shapes": shapes})
     if trace is not None:
         from chipbench import reduce
 

@@ -81,3 +81,31 @@ def leaf_norms(tree) -> jax.Array:
     """Per-leaf Euclidean norms in float32, in tree-flatten order."""
     return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
                       for x in jax.tree.leaves(tree)])
+
+
+BLOCKS = 64
+MIN_BLOCK = 1 << 20
+
+
+def _num_blocks(n: int) -> int:
+    """The most blocks, at most ``BLOCKS`` and a power of two, into which
+    ``n`` elements cut evenly with at least ``MIN_BLOCK`` in each."""
+    k = BLOCKS
+    while k > 1 and (n % k or n // k < MIN_BLOCK):
+        k //= 2
+    return k
+
+
+@jax.jit
+def block_norms(tree) -> jax.Array:
+    """Euclidean norms in float32 of contiguous blocks of each leaf, in
+    tree-flatten order: a leaf is cut into ``_num_blocks`` equal blocks in
+    its row-major order.  At GPT-A's widths that is 64 blocks of whole
+    rows of the embedding, the LM head and the FFN matrices, 32 of the
+    attention matrices and one of each norm scale; at a test's widths,
+    one block a leaf."""
+    out = []
+    for x in jax.tree.leaves(tree):
+        x = x.astype(jnp.float32).reshape(_num_blocks(x.size), -1)
+        out.append(jnp.sqrt(jnp.sum(jnp.square(x), axis=1)))
+    return jnp.concatenate(out)
