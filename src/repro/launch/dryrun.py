@@ -117,9 +117,9 @@ def collective_bytes(hlo_text: str, pod_stride: int) -> Dict[str, float]:
     return out
 
 
-def _sds_params(model, mesh, fsdp: bool = False):
+def _sds_params(model, mesh, fsdp: bool = False, stages: bool = True):
     p_shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    shardings = make_param_shardings(p_shapes, mesh, fsdp=fsdp)
+    shardings = make_param_shardings(p_shapes, mesh, fsdp=fsdp, stages=stages)
     return jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         p_shapes,
@@ -166,10 +166,12 @@ def build_lowerable(arch: str, shape: str, multi_pod: bool, boundary: str = "str
     opt_cfg = OptimizerConfig()
 
     with jax.set_mesh(mesh):
-        params_sds = _sds_params(model, mesh, fsdp=fsdp)
+        # pods are pipeline stages in training, replicas in serving
+        params_sds = _sds_params(model, mesh, fsdp=fsdp, stages=kind == "train")
         if kind == "train":
             if multi_pod:
-                loss_fn = make_pipeline_loss(cfg, mesh, n_micro=n_micro, boundary=boundary)
+                loss_fn = make_pipeline_loss(cfg, mesh, n_micro=n_micro, boundary=boundary,
+                                             fsdp=fsdp)
                 step = make_train_step(loss_fn, opt_cfg, loss_has_metrics=False)
             else:
                 step = make_train_step(model.loss, opt_cfg)

@@ -39,7 +39,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.modules import ModelConfig
 from repro.models.transformer import PipelineParts, build_pipeline_parts
-from repro.parallel.sharding import constrain, constraints_disabled
+from repro.parallel.sharding import constraints_disabled, make_param_shardings
+from repro.scopes import EMBED, PIPELINE, STAGE_BOUNDARY
 
 
 def pad_layer_stack(layers: Any, num_stages: int) -> Any:
@@ -71,8 +72,13 @@ def make_pipeline_loss(
     *,
     n_micro: int = 4,
     boundary: str = "striped",
+    fsdp: bool = False,
 ) -> Callable[[Dict, Dict], jax.Array]:
-    """Build loss(params, batch) running PP over the mesh's ``pod`` axis."""
+    """Build loss(params, batch) running PP over the mesh's ``pod`` axis.
+
+    ``fsdp`` names the layout the caller places the parameters in
+    (``make_param_shardings``); their gradients come back in it, so a
+    train step hands back parameters and moments as it took them."""
     assert boundary in ("striped", "direct")
     assert not cfg.tie_embeddings, (
         "pipeline requires untied embeddings: a tied table is consumed in "
@@ -83,6 +89,11 @@ def make_pipeline_loss(
     DP = mesh.shape["data"]
 
     def loss_fn(params: Dict, batch: Dict) -> jax.Array:
+        # a no-op forward whose transpose lays the gradients out as the
+        # parameters: left to GSPMD, the norm scales' come back split
+        # over ``model`` and every later step compiles a second program
+        params = jax.lax.with_sharding_constraint(
+            params, make_param_shardings(params, mesh, fsdp=fsdp))
         # ---- static input prep (ints only; differentiable inputs and the
         # embedding lookup live INSIDE the manual region — a `take` whose
         # cotangent crosses the GSPMD/manual boundary trips an XLA SPMD
@@ -157,7 +168,8 @@ def make_pipeline_loss(
             axis_names={"pod", "data"},
             check_vma=False,
         )
-        loss, aux = sm(layers, rest, inp_mb, pos_mb, t_mb, m_mb)
+        with jax.named_scope(PIPELINE):
+            loss, aux = sm(layers, rest, inp_mb, pos_mb, t_mb, m_mb)
         return loss + aux
 
     return loss_fn
@@ -181,16 +193,18 @@ def _pipeline_inner(
     if token_input:
         # embedding lookup with device-local indices: the VJP scatter-add
         # stays inside the manual region (no partitioned scatter).
-        x_mb = jnp.take(rest["embed"], inp_mb, axis=0).astype(cfg.dtype)
+        with jax.named_scope(EMBED):
+            x_mb = jnp.take(rest["embed"], inp_mb, axis=0).astype(cfg.dtype)
     else:
         x_mb = inp_mb
     mb, T, Dm = x_mb.shape[1:]
 
     def stage_fn(x, positions):
         def body(h, lp):
-            # model-internal sharding constraints reference the (manual)
-            # data axis; drop them here — GSPMD still propagates the
-            # model-axis (TP) shardings from the parameters.
+            # the layers' own sharding constraints stay off here (XLA's
+            # partitioner rejects some in the manual subgroup: MoE
+            # dispatch) — GSPMD still propagates the model-axis (TP)
+            # shardings from the parameters.
             with constraints_disabled():
                 h, aux = parts.layer(lp, rest, h, positions)
             return h, aux
@@ -218,24 +232,25 @@ def _pipeline_inner(
         # (repro.parallel.sharding.constrain treats all-None as a no-op),
         # otherwise GSPMD propagation picks its own layout and the two
         # modes become indistinguishable.
-        if boundary == "striped":
-            y_send = _constrain_auto(y, P(None, None, "model"))
-            buf_next = jax.lax.ppermute(
-                y_send, "pod", [(i, i + 1) for i in range(S - 1)]
-            )
-        else:
-            # naive transport: the model-replicated activation crosses the
-            # pod DCN as-is.  The optimization_barrier pins the layout —
-            # without it XLA's partitioner reshards before the permute and
-            # re-gathers after, i.e. GSPMD performs the Atlas striping
-            # automatically (see EXPERIMENTS.md §Perf B).
-            y_send = _constrain_auto(y, P(None, None, None))
-            y_send = jax.lax.optimization_barrier(y_send)
-            buf_next = jax.lax.ppermute(
-                y_send, "pod", [(i, i + 1) for i in range(S - 1)]
-            )
-            buf_next = jax.lax.optimization_barrier(buf_next)
-        buf_next = _constrain_auto(buf_next, P(None, None, None))
+        with jax.named_scope(STAGE_BOUNDARY):
+            if boundary == "striped":
+                y_send = _constrain_auto(y, P(None, None, "model"))
+                buf_next = jax.lax.ppermute(
+                    y_send, "pod", [(i, i + 1) for i in range(S - 1)]
+                )
+            else:
+                # naive transport: the model-replicated activation crosses the
+                # pod DCN as-is.  The optimization_barrier pins the layout —
+                # without it XLA's partitioner reshards before the permute and
+                # re-gathers after, i.e. GSPMD performs the Atlas striping
+                # automatically (see EXPERIMENTS.md §Perf B).
+                y_send = _constrain_auto(y, P(None, None, None))
+                y_send = jax.lax.optimization_barrier(y_send)
+                buf_next = jax.lax.ppermute(
+                    y_send, "pod", [(i, i + 1) for i in range(S - 1)]
+                )
+                buf_next = jax.lax.optimization_barrier(buf_next)
+            buf_next = _constrain_auto(buf_next, P(None, None, None))
 
         # ---- loss on the last stage ----
         m_out = t - (S - 1)

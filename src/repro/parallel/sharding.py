@@ -44,7 +44,10 @@ def _ambient_mesh():
 
 
 def _fit_spec(shape: Tuple[int, ...], spec: P, mesh) -> Optional[P]:
-    """Drop axes that don't divide the dim; None if nothing remains."""
+    """Drop axes that don't divide the dim; None if nothing remains.
+    Trailing unsplit dims are left out, as a compiled step writes the
+    shardings of what it returns, so a state placed by these specs and
+    the state a step hands back compare equal."""
     axes = dict(mesh.shape)
     fitted = []
     changed = False
@@ -66,21 +69,40 @@ def _fit_spec(shape: Tuple[int, ...], spec: P, mesh) -> Optional[P]:
             changed = True
     if all(f is None for f in fitted):
         return None
+    while fitted[-1] is None:
+        fitted.pop()
     return P(*fitted)
+
+
+def _auto_only(spec: P, mesh) -> P:
+    """``spec`` without the axes ``mesh`` marks ``Manual``: inside a
+    ``shard_map`` those are already split by hand, and a constraint may
+    name only the axes left to GSPMD."""
+    manual = set(mesh.manual_axes)
+    if not manual:
+        return spec
+
+    def keep(entry):
+        names = entry if isinstance(entry, tuple) else (entry,)
+        left = tuple(n for n in names if n is not None and n not in manual)
+        return (left if len(left) > 1 else left[0]) if left else None
+
+    return P(*(keep(e) for e in spec))
 
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:
     """with_sharding_constraint that degrades gracefully.
 
     No-op when there is no ambient mesh (plain CPU tests) or when no axis
-    of ``spec`` fits the array's shape.
+    of ``spec`` fits the array's shape; inside a ``shard_map`` it leaves
+    out the manual axes (the pipeline's ``pod`` and ``data``).
     """
     if getattr(_TLS, "off", False):
         return x
     mesh = _ambient_mesh()
     if mesh is None or not hasattr(x, "shape"):
         return x
-    fitted = _fit_spec(x.shape, spec, mesh)
+    fitted = _fit_spec(x.shape, _auto_only(spec, mesh), mesh)
     if fitted is None:
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, fitted))
@@ -198,8 +220,15 @@ def make_param_shardings(
     stacked_prefixes=("layers", "groups"),
     *,
     fsdp: bool = False,
+    stages: bool = True,
 ):
-    """Build a NamedSharding pytree for a params(-shape) pytree."""
+    """Build a NamedSharding pytree for a params(-shape) pytree.
+
+    ``stages``: where the mesh has more than one ``pod``, split the
+    stacked layer axis over it, so that each pipeline stage holds only its
+    own layers and their optimizer state (``parallel/pipeline.py``); pass
+    False where the pods are replicas of the whole model."""
+    split_layers = stages and dict(mesh.shape).get("pod", 1) > 1
 
     def one(path, leaf):
         names = tuple(
@@ -207,6 +236,8 @@ def make_param_shardings(
         )
         stacked = any(n in stacked_prefixes for n in names)
         for spec in param_spec_candidates(names or ("",), leaf.shape, stacked):
+            if stacked and split_layers:
+                spec = P("pod", *tuple(spec)[1:])
             fitted = _fit_spec(leaf.shape, spec, mesh)
             if fitted is not None:
                 if fsdp:

@@ -12,7 +12,21 @@ the same with or without them.
 - ``ffn``: pre-norm, FFN or MoE layer and its residual add.
 - ``loss_head``: the chunked head matmul and cross-entropy.
 - ``optimizer``: the AdamW update (norm, clip, moments, decay, write).
+
+The pipelined step (``parallel/pipeline.py``) adds two, around the
+layer scopes, so that a layer's time stays its own (the innermost scope
+counts):
+
+- ``pipeline``: the ``shard_map`` over ``pod``: the fill-drain rotation,
+  the masking of clipped microbatches, and the ``pod`` reductions of the
+  gradients of the parameters every stage holds.
+- ``stage_boundary``: inside ``pipeline``, the ``ppermute`` that hands
+  an activation to the next stage, with its layouts on either side.
+
+``SCOPES`` are the layer scopes of every train step, ``ALL`` every name.
 """
 
 EMBED, ATTN, FFN, LOSS_HEAD, OPTIMIZER = SCOPES = (
     "embed", "attn", "ffn", "loss_head", "optimizer")
+PIPELINE, STAGE_BOUNDARY = "pipeline", "stage_boundary"
+ALL = SCOPES + (PIPELINE, STAGE_BOUNDARY)
