@@ -28,6 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS, canon
@@ -70,31 +71,30 @@ def _shape_bytes(type_str: str) -> int:
     return n * b
 
 
-def _crosses_pod(line: str, pod_stride: int) -> bool:
-    """True if the collective's device groups span pods (device id // stride
-    differs within a group). Device order is pod-major."""
-    m = re.search(r"replica_groups=\{([^}]*)\}", line)
+def _replica_groups(line: str):
+    """A collective's device groups in either of HLO's forms: explicit,
+    ``{{0,2},{1,3}}``, or iota, ``[2,2]<=[2,2]T(1,0)`` (the ids of the
+    shape in brackets, transposed by ``T``, read as groups by rows)."""
+    m = re.search(r"replica_groups=\{((?:\{[0-9,]*\},?)*)\}", line)
     if m:
-        for grp in m.group(1).split("},{"):
-            ids = [int(x) for x in re.findall(r"\d+", grp)]
-            if ids and (min(ids) // pod_stride) != (max(ids) // pod_stride):
-                return True
-        return False
-    m = re.search(r"replica_groups=\[\d+,\d+\]<=\[([0-9,]+)\](.*)", line)
-    # iota group list form: conservative — check source_target_pairs next
+        return [[int(x) for x in g.split(",") if x]
+                for g in re.findall(r"\{([0-9,]*)\}", m.group(1))]
+    m = re.search(r"replica_groups=\[(\d+),(\d+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?", line)
+    if not m:
+        return []
+    ids = np.arange(int(m[1]) * int(m[2])).reshape([int(d) for d in m[3].split(",")])
+    if m[4]:
+        ids = ids.transpose([int(d) for d in m[4].split(",")])
+    return ids.reshape(int(m[1]), int(m[2])).tolist()
+
+
+def _crosses_pod(line: str, pod_stride: int) -> bool:
+    """True if the collective's device groups, or its source-target pairs,
+    span pods (device id // stride differs). Device order is pod-major."""
     if "source_target_pairs=" in line:
         pairs = re.findall(r"\{(\d+),(\d+)\}", line.split("source_target_pairs=")[1])
         return any(int(a) // pod_stride != int(b) // pod_stride for a, b in pairs)
-    if m:
-        # iota form e.g. [16,32]<=[32]: groups of contiguous stride —
-        # groups span pods iff group size > pod_stride ... approximate by
-        # dims: [n_groups, group_size]
-        pre = line.split("replica_groups=")[1]
-        mm = re.match(r"\[(\d+),(\d+)\]", pre)
-        if mm:
-            g = int(mm.group(2))
-            return g > pod_stride
-    return False
+    return any(len({i // pod_stride for i in g}) > 1 for g in _replica_groups(line))
 
 
 def collective_bytes(hlo_text: str, pod_stride: int) -> Dict[str, float]:

@@ -132,27 +132,20 @@ class PipelineParts:
     same SPMD program can serve every pipeline stage."""
 
     layer_key: str  # params key holding the (L, ...) stacked layer params
-    embed: Callable[[Params, Dict], Tuple[jax.Array, jax.Array]]  # -> x, positions
+    embed: Callable[[Params, jax.Array], jax.Array]  # (params, tokens) -> rows
     layer: Callable[[Params, Params, jax.Array, jax.Array], Tuple[jax.Array, jax.Array]]
     # (layer_params, full_params, x, positions) -> (x, aux)
     final_loss: Callable[[Params, jax.Array, jax.Array, Optional[jax.Array]], jax.Array]
     # (full_params, x, targets, mask) -> scalar CE
+    # the top-level params ``embed`` and ``final_loss`` read (a tied head
+    # reads only "embed"); the pipeline runs those two outside its stages
+    io_keys: Tuple[str, ...] = ("embed", "lm_head", "final_norm")
 
 
 def build_pipeline_parts(cfg: ModelConfig) -> PipelineParts:
-    def embed(params, batch):
-        if "embeds" in batch:
-            x = batch["embeds"].astype(cfg.dtype)
-        else:
-            x = _embed_tokens(params, cfg, batch["tokens"])
-        if "positions" in batch:
-            positions = batch["positions"]
-        elif cfg.mrope_sections is not None:
-            pos2 = _default_positions(x.shape[:2])
-            positions = jnp.broadcast_to(pos2[None], (3,) + pos2.shape)
-        else:
-            positions = _default_positions(x.shape[:2])
-        return x, positions
+    @jax.named_scope(EMBED)
+    def embed(params, tokens):
+        return jnp.take(params["embed"], tokens, axis=0)
 
     def final_loss(params, x, targets, mask):
         x = rmsnorm(params["final_norm"], x)

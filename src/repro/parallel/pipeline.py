@@ -2,15 +2,25 @@
 TPU (DESIGN.md §2).
 
 The ``pod`` mesh axis carries pipeline stages; ``data`` carries DP within a
-pod; ``model`` carries TP.  The step is a circular-rotation microbatch
-pipeline inside a *partial-auto* shard_map: manual over {pod, data}
-(``lax.ppermute`` moves stage-boundary activations across the inter-pod
-DCN; per-data-shard token work is local, which also sidesteps XLA SPMD
-partitioner failures around MoE gather/scatter in manual subgroups),
-while GSPMD keeps handling the ``model`` axis (TP) automatically.
-Autodiff through the scan+ppermute yields the reversed-permutation
-backward pipeline for free; the psum over ``data`` in the loss transposes
-into the DP gradient all-reduce.
+pod; ``model`` carries TP.  Only the layer stack is pipelined: a
+circular-rotation microbatch pipeline inside a *partial-auto* shard_map,
+manual over {pod, data} (``lax.ppermute`` moves stage-boundary activations
+across the inter-pod DCN; per-data-shard token work is local, which also
+sidesteps XLA SPMD partitioner failures around MoE gather/scatter in manual
+subgroups), while GSPMD keeps handling the ``model`` axis (TP)
+automatically.  Autodiff through the scan+ppermute yields the
+reversed-permutation backward pipeline for free.
+
+Into the manual region go the layers, each stage its own slice, and the
+other parameters the layers read (zamba2's ``shared_attn``): their
+gradients are summed over ``data`` (DP) and, for the shared ones, over
+``pod``.  The embedding lookup before the stages and the final norm and
+loss head after them (``PipelineParts.io_keys``) run once in the GSPMD
+region around it, as in the plain model: a parameter taken into the
+region whole would have its gradient summed over ``pod``, which for the
+tables only stage 0 and the last stage read adds zeros.  What crosses
+``pod`` for them is the activations: the last stage's outputs on their way
+to the head, and the input's cotangent back.
 
 Boundary modes — the TPU-native reading of the paper's two transports:
   * ``direct``  (Varuna / PyTorch-one-TCP analogue): the activation is
@@ -35,7 +45,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.modules import ModelConfig
 from repro.models.transformer import PipelineParts, build_pipeline_parts
@@ -80,10 +90,7 @@ def make_pipeline_loss(
     (``make_param_shardings``); their gradients come back in it, so a
     train step hands back parameters and moments as it took them."""
     assert boundary in ("striped", "direct")
-    assert not cfg.tie_embeddings, (
-        "pipeline requires untied embeddings: a tied table is consumed in "
-        "both the GSPMD and manual regions, which XLA's partitioner rejects"
-    )
+    assert not cfg.tie_embeddings, "pipeline requires untied embeddings"
     parts = build_pipeline_parts(cfg)
     S = mesh.shape["pod"]
     DP = mesh.shape["data"]
@@ -94,10 +101,6 @@ def make_pipeline_loss(
         # over ``model`` and every later step compiles a second program
         params = jax.lax.with_sharding_constraint(
             params, make_param_shardings(params, mesh, fsdp=fsdp))
-        # ---- static input prep (ints only; differentiable inputs and the
-        # embedding lookup live INSIDE the manual region — a `take` whose
-        # cotangent crosses the GSPMD/manual boundary trips an XLA SPMD
-        # partitioner CHECK) ----
         if "embeds" in batch:
             B, T = batch["embeds"].shape[:2]
         else:
@@ -127,50 +130,64 @@ def make_pipeline_loss(
             mask = batch.get("mask")
             if mask is None:
                 mask = jnp.ones_like(targets, jnp.float32)
-        t_mb = targets.reshape(n_micro, mb, T)
-        m_mb = mask.reshape(n_micro, mb, T)
 
+        # the embedding is looked up here, in the GSPMD region, and enters
+        # the stages in f32: its cotangent leaves them summed over ``pod``
+        # (zero but on stage 0), a sum XLA's CPU compiler fails on in bf16
         if "embeds" in batch:
-            inp_mb = batch["embeds"].astype(cfg.dtype).reshape(n_micro, mb, T, -1)
-            inp_spec = P(None, "data", None, None)
-            token_input = False
+            x_mb = batch["embeds"].reshape(n_micro, mb, T, -1)
         else:
-            inp_mb = batch["tokens"].reshape(n_micro, mb, T)
-            inp_spec = P(None, "data", None)
-            token_input = True
+            x_mb = parts.embed(params, batch["tokens"].reshape(n_micro, mb, T))
+        x_mb = x_mb.astype(jnp.float32)
 
         layers = pad_layer_stack(params[parts.layer_key], S)
-        rest = {k: v for k, v in params.items() if k != parts.layer_key}
+        # only what the layers read enters the region manual over ``pod``:
+        # the gradient of a parameter every stage reads there is summed
+        # over ``pod``, which for the embedding and head would add zeros
+        shared = {k: v for k, v in params.items()
+                  if k != parts.layer_key and k not in parts.io_keys}
 
         inner = functools.partial(
-            _pipeline_inner,
-            parts=parts,
-            cfg=cfg,
-            S=S,
-            DP=DP,
-            n_micro=n_micro,
-            boundary=boundary,
-            token_input=token_input,
+            _pipeline_inner, parts=parts, cfg=cfg, S=S, DP=DP,
+            n_micro=n_micro, boundary=boundary,
         )
         sm = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(
                 jax.tree.map(lambda _: P("pod"), layers),
-                jax.tree.map(lambda _: P(), rest),
-                inp_spec,
+                jax.tree.map(lambda _: P(), shared),
+                P(None, "data", None, None),
                 pos_spec,
-                P(None, "data", None),
-                P(None, "data", None),
             ),
-            out_specs=(P(), P()),
+            out_specs=(P("pod", None, "data", None, None), P()),
             # partial-auto: GSPMD keeps handling TP on ``model``
             axis_names={"pod", "data"},
             check_vma=False,
         )
         with jax.named_scope(PIPELINE):
-            loss, aux = sm(layers, rest, inp_mb, pos_mb, t_mb, m_mb)
-        return loss + aux
+            ys, aux = sm(layers, shared, x_mb, pos_mb)
+            with jax.named_scope(STAGE_BOUNDARY):
+                # the last stage's outputs, to every pod: one sum over
+                # ``pod`` forward, and a local select backward (``ys[S-1]``
+                # would permute both ways)
+                last = (jnp.arange(S) == S - 1)[:, None, None, None, None]
+                h = jnp.sum(jnp.where(last, ys, 0), axis=0)
+
+        # the loss is the mean over microbatches and data shards of each
+        # one's masked mean CE: weights that sum to at most 1 turn the
+        # head's masked mean (over max(sum, 1)) into that mean
+        m_g = mask.astype(jnp.float32).reshape(n_micro, DP, mb // DP, T)
+        cnt = jnp.maximum(jnp.sum(m_g, axis=(2, 3), keepdims=True), 1.0)
+        w_mb = (m_g / (cnt * (n_micro * DP))).reshape(n_micro, mb, T)
+        # microbatch-minor rows keep a batch split over ``data`` split;
+        # the head runs whole on every pod, so its gradient needs no sum
+        # over ``pod`` (left to GSPMD, it splits the head over ``pod``)
+        rows = lambda a: a.swapaxes(0, 1).reshape(B, *a.shape[2:])
+        x = jax.lax.with_sharding_constraint(rows(h), NamedSharding(mesh, P("data")))
+        t_mb = targets.reshape(n_micro, mb, T)
+        ce = parts.final_loss(params, x, rows(t_mb), rows(w_mb))
+        return ce + aux
 
     return loss_fn
 
@@ -183,20 +200,17 @@ def _constrain_auto(x, spec):
 
 
 def _pipeline_inner(
-    layers, rest, inp_mb, pos_mb, t_mb, m_mb, *, parts, cfg, S, DP,
-    n_micro, boundary, token_input,
+    layers, shared, x_mb, pos_mb, *, parts, cfg, S, DP, n_micro, boundary,
 ):
-    """Manual over {pod, data}: ``layers`` is this stage's (L/S, ...) slice;
-    token arrays are this data-shard's slice."""
+    """Manual over {pod, data}: ``layers`` is this stage's (L/S, ...) slice,
+    ``shared`` the other parameters the layers read; the microbatches are
+    this data shard's. Returns this stage's outputs of the rotations in
+    which the last stage finishes microbatch 0, 1, ..., with a leading
+    stage axis, and the layers' aux loss."""
     my = jax.lax.axis_index("pod")  # this pod's stage index
     steps = n_micro + S - 1
-    if token_input:
-        # embedding lookup with device-local indices: the VJP scatter-add
-        # stays inside the manual region (no partitioned scatter).
-        with jax.named_scope(EMBED):
-            x_mb = jnp.take(rest["embed"], inp_mb, axis=0).astype(cfg.dtype)
-    else:
-        x_mb = inp_mb
+    with jax.named_scope(EMBED):
+        x_mb = x_mb.astype(cfg.dtype)
     mb, T, Dm = x_mb.shape[1:]
 
     def stage_fn(x, positions):
@@ -206,7 +220,7 @@ def _pipeline_inner(
             # dispatch) — GSPMD still propagates the model-axis (TP)
             # shardings from the parameters.
             with constraints_disabled():
-                h, aux = parts.layer(lp, rest, h, positions)
+                h, aux = parts.layer(lp, shared, h, positions)
             return h, aux
 
         if cfg.remat != "none":
@@ -251,21 +265,11 @@ def _pipeline_inner(
                 )
                 buf_next = jax.lax.optimization_barrier(buf_next)
             buf_next = _constrain_auto(buf_next, P(None, None, None))
-
-        # ---- loss on the last stage ----
-        m_out = t - (S - 1)
-        mo = jnp.clip(m_out, 0, n_micro - 1)
-        with constraints_disabled():
-            ce = parts.final_loss(rest, y, idx(t_mb, mo), idx(m_mb, mo))
-        valid_out = jnp.logical_and(m_out >= 0, m_out < n_micro)
-        is_last = my == (S - 1)
-        ce = ce * valid_out.astype(jnp.float32) * is_last.astype(jnp.float32)
-        return buf_next, (ce, aux)
+        return buf_next, (y, aux)
 
     buf0 = jnp.zeros((mb, T, Dm), x_mb.dtype)
-    _, (ces, auxs) = jax.lax.scan(rot, buf0, jnp.arange(steps))
-    # psum over pod picks up the (single) last stage; psum over data
-    # averages DP shards — its transpose is the DP gradient all-reduce.
-    loss = jax.lax.psum(jnp.sum(ces), ("pod", "data")) / (n_micro * DP)
+    _, (ys, auxs) = jax.lax.scan(rot, buf0, jnp.arange(steps))
+    # the layers' aux loss: summed over the stages, averaged over the
+    # microbatches and data shards
     aux = jax.lax.psum(jnp.sum(auxs), ("pod", "data")) / (n_micro * DP)
-    return loss, aux
+    return ys[None, S - 1:], aux

@@ -272,9 +272,8 @@ print("OK")
 def test_pipeline_scopes_reach_the_compiled_step():
     """The pipelined train step's optimized HLO on ``(pod 2, data 1,
     model 2)``: every ``collective-permute`` is innermost in
-    ``stage_boundary``, the ``pod`` reductions of the gradients of the
-    parameters every stage holds are in ``pipeline``, and the token
-    gather is in ``embed``."""
+    ``stage_boundary``, the ``pod`` reduction of the cotangents that leave
+    the stages is in ``pipeline``, and the token gather is in ``embed``."""
     out = _run(PREAMBLE + """
 import re
 from repro.optim.optimizer import OptimizerConfig, init_opt_state, make_train_step
@@ -292,14 +291,15 @@ with jax.set_mesh(mesh):
     loss = make_pipeline_loss(cfg, mesh, n_micro=4, boundary="striped")
     step = jax.jit(make_train_step(loss, OptimizerConfig(), loss_has_metrics=False))
     text = step.lower(params, jax.eval_shape(init_opt_state, params), batch).compile().as_text()
-ops = re.findall(r"= \\S+ ([\\w\\-]+)\\(.*op_name=\\"([^\\"]*)\\"", text)
+# an op's type is a shape or a tuple of them (an all-reduce of several)
+ops = re.findall(r"= (?:\\((?:[^()]|\\([^()]*\\))*\\)|\\S+) ([\\w\\-]+)\\(.*op_name=\\"([^\\"]*)\\"", text)
 innermost = lambda path: next(
     (t for t in reversed(re.split(r"[/(),]", path)) if t in ALL), None)
 permutes = [p for op, p in ops if op.startswith("collective-permute")]
 assert permutes and all(innermost(p) == "stage_boundary" for p in permutes), permutes
 pod_sums = [p for op, p in ops if op.startswith("all-reduce") and innermost(p) == "pipeline"]
 assert any(p.endswith("shard_map/psum") for p in pod_sums), pod_sums
-gathers = [p for op, p in ops if op == "gather" and "shard_map" in p]
+gathers = [p for op, p in ops if op == "gather"]
 assert gathers and all(innermost(p) == "embed" for p in gathers), gathers
 print("OK", len(permutes), len(pod_sums))
 """, devices=4)
@@ -342,3 +342,114 @@ assert all(jax.tree.leaves(kept)), kept
 print("OK")
 """)
     assert "OK" in out
+
+
+@pytest.mark.parametrize("arch", ["gpt_a", "zamba2_2p7b"])
+def test_only_what_the_layers_read_is_reduced_over_pod(arch):
+    """The pipelined train step on ``(pod 2, data 1, model 2)``, compiled
+    at smoke size with a vocabulary no other width shares: no ``pod``
+    reduction, and no all-reduce innermost in ``pipeline``, carries an
+    operand as long as the vocabulary (or its ``model`` split), and every
+    ``pod`` reduction of a parameter-shaped operand is the gradient of a
+    parameter the layers read, outside ``io_keys``: none for GPT-A,
+    ``shared_attn`` for zamba2."""
+    out = _run(PREAMBLE + f"ARCH = {arch!r}" + r"""
+import dataclasses, re
+from repro.launch.dryrun import _crosses_pod
+from repro.models.transformer import build_pipeline_parts
+from repro.optim.optimizer import OptimizerConfig, init_opt_state, make_train_step
+from repro.parallel.sharding import make_batch_shardings, make_param_shardings
+from repro.scopes import ALL
+cfg = dataclasses.replace(get_smoke_config(ARCH), vocab_size=640)
+m = build_model(cfg)
+parts = build_pipeline_parts(cfg)
+with jax.set_mesh(mesh):
+    shapes = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    sh = make_param_shardings(shapes, mesh)
+    sds = lambda t, s: jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), t, s)
+    params = sds(shapes, sh)
+    batch = {"tokens": jax.ShapeDtypeStruct((4, 32), jnp.int32)}
+    batch = sds(batch, make_batch_shardings(batch, mesh))
+    loss = make_pipeline_loss(cfg, mesh, n_micro=4, boundary="striped")
+    step = jax.jit(make_train_step(loss, OptimizerConfig(), loss_has_metrics=False))
+    text = step.lower(params, jax.eval_shape(init_opt_state, params), batch).compile().as_text()
+local = {k: {tuple(s.shard_shape(x.shape)) for x, s in
+              zip(jax.tree.leaves(shapes[k]), jax.tree.leaves(sh[k]))} for k in shapes}
+shared = set().union(*(local[k] for k in local
+                       if k != parts.layer_key and k not in parts.io_keys))
+param_shaped = set().union(*local.values()) - {()}
+vocab = {cfg.vocab_size, cfg.vocab_size // mesh.shape["model"]}
+per_pod = mesh.shape["data"] * mesh.shape["model"]  # device ids are pod-major
+innermost = lambda path: next(
+    (t for t in reversed(re.split(r"[/(),]", path)) if t in ALL), None)
+pod_shapes, pipeline_shapes = [], []
+for line in text.splitlines():
+    r = re.match(r"\s*%\S+ = (.+?) all-reduce(-start)?\(", line)
+    if not r:
+        continue
+    dims = [tuple(int(d) for d in s.split(",") if d)
+            for s in re.findall(r"[a-z][a-z0-9]*\[([0-9,]*)\]", r.group(1))]
+    if _crosses_pod(line, per_pod):
+        pod_shapes += dims
+    if innermost(re.search(r'op_name="([^"]*)"', line).group(1)) == "pipeline":
+        pipeline_shapes += dims
+assert pod_shapes, "no pod reduction found"
+assert not [s for s in pod_shapes + pipeline_shapes if vocab & set(s)], (pod_shapes, pipeline_shapes)
+reduced = {s for s in pod_shapes if s in param_shaped}
+assert reduced <= shared, (reduced, shared)
+assert bool(reduced) == bool(shared), (reduced, shared)
+print("OK", sorted(reduced))
+""", devices=4)
+    assert "OK" in out
+
+
+def test_pipelined_loss_weighs_each_microbatch_alike():
+    """With a mask that differs between microbatches, the pipelined loss is
+    the mean of each microbatch's plain ``model.loss``, not the masked mean
+    over all their tokens (which the sharpened head sets well apart)."""
+    out = _run(PREAMBLE + """
+cfg = get_smoke_config("gpt_a")
+m = build_model(cfg)
+params = m.init(jax.random.PRNGKey(0))
+# a sharp head spreads the tokens' CE, so the two weightings differ
+params["lm_head"] = params["lm_head"] * 16
+tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)
+labels = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+lengths = jnp.array([31, 17, 6, 2])  # one row a microbatch
+mask = (jnp.arange(32)[None] < lengths[:, None]).astype(jnp.float32)
+batch = {"tokens": tokens, "labels": labels, "mask": mask}
+plain = jax.jit(lambda p, b: m.loss(p, b)[0])
+ref = sum(float(plain(params, {k: v[i:i + 1] for k, v in batch.items()}))
+          for i in range(4)) / 4
+pooled = float(plain(params, batch))
+with jax.set_mesh(mesh):
+    s = float(jax.jit(make_pipeline_loss(cfg, mesh, n_micro=4))(params, batch))
+# bf16 compute in both, summed in another order (as the train step test)
+tol = 1e-3 * abs(ref)
+assert abs(s - ref) < tol, (s, ref, pooled)
+assert abs(pooled - ref) > 10 * tol, (pooled, ref)
+print("OK", s, ref, pooled)
+""", devices=4)
+    assert "OK" in out
+
+
+@pytest.mark.parametrize("groups,crosses", [
+    ("replica_groups={{0,2},{1,3}}", True),
+    ("replica_groups={{0,1},{2,3}}", False),
+    ("replica_groups={{0},{1},{2},{3}}", False),
+    ("replica_groups=[2,2]<=[4]", False),
+    ("replica_groups=[2,2]<=[2,2]T(1,0)", True),
+    ("replica_groups=[1,4]<=[4]", True),
+    ("source_target_pairs={{0,2},{1,3}}", True),
+    ("source_target_pairs={{0,0},{2,1},{1,2},{3,3}}", True),
+])
+def test_crosses_pod_reads_every_form_of_device_groups(groups, crosses):
+    """``collective_bytes`` counts a collective as inter-pod (``dcn``) by
+    its device groups on ``(pod 2, data 1, model 2)``, pod-major: the
+    transposed iota form ``[2,2]<=[2,2]T(1,0)`` is the groups {0,2} and
+    {1,3}, across the pods."""
+    from repro.launch.dryrun import _crosses_pod
+
+    line = f"%ar = f32[8]{{0}} all-reduce(f32[8]{{0}} %x), {groups}, to_apply=%add"
+    assert _crosses_pod(line, pod_stride=2) == crosses
